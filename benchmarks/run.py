@@ -59,6 +59,9 @@ def main() -> None:
                     help="registry history path (with --record)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (bench_async, bench_obs, bench_protocol,
                             bench_sparse, bench_wire, fig2_sensitivity,
                             fig3_ras, fig4_scale, fig5_audit,

@@ -24,7 +24,8 @@ Two gossip schedules:
 
 Within-host kernel routing: with ``use_kernels=True`` the dense schedule's
 ``W @ s`` runs through the MXU-shaped ``repro.kernels.pushsum_mix`` Pallas
-block (one VMEM-resident product per leaf instead of an HBM-bound einsum).
+block (one VMEM-resident product per leaf instead of an HBM-bound einsum),
+as long as the (N, N) block fits VMEM; above that N the jnp path runs.
 The circulant schedule has no kernel variant by design — its rolls are
 permutations, pure data movement that XLA already lowers optimally (and to
 collective-permutes when the node axis is sharded), so there is no MXU op
@@ -85,6 +86,13 @@ def init_push_sum(s: PyTree) -> PushSumState:
     return PushSumState(s=s, a=jnp.ones((n,), dtype=jnp.float32))
 
 
+# Every mixing contraction runs at full f32 precision. On TPU an f32 dot
+# otherwise defaults to a single bf16 pass, which breaks push-sum's f32
+# mass conservation and stalls consensus near bf16 resolution; on CPU the
+# setting changes nothing.
+MIX_PRECISION = jax.lax.Precision.HIGHEST
+
+
 def _mix_dense(w: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     # out[i] = sum_j w[i, j] x[j]. Leaves with fewer than 3 trailing
     # columns — the (N,) push-sum weights especially — are zero-padded to
@@ -102,9 +110,11 @@ def _mix_dense(w: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
         flat = x.reshape(n, d)
         padded = jnp.concatenate([flat, jnp.zeros((n, 3 - d), flat.dtype)],
                                  axis=1)
-        out = jnp.einsum("ij,jd->id", w.astype(x.dtype), padded)
+        out = jnp.einsum("ij,jd->id", w.astype(x.dtype), padded,
+                         precision=MIX_PRECISION)
         return out[:, :d].reshape(x.shape)
-    return jnp.einsum("ij,j...->i...", w.astype(x.dtype), x)
+    return jnp.einsum("ij,j...->i...", w.astype(x.dtype), x,
+                      precision=MIX_PRECISION)
 
 
 def sparse_mix(idx: jnp.ndarray, vals: jnp.ndarray,
@@ -132,10 +142,20 @@ def sparse_mix(idx: jnp.ndarray, vals: jnp.ndarray,
             [flat, jnp.zeros((b, k, 3 - d), flat.dtype)], axis=2)
     out = jax.lax.dot_general(
         vals.astype(flat.dtype)[:, None, :], flat,
-        (((2,), (1,)), ((0,), (0,))))[:, 0]
+        (((2,), (1,)), ((0,), (0,))), precision=MIX_PRECISION)[:, 0]
     if d < 3:
         out = out[:, :d]
     return out.reshape((b,) + x.shape[1:])
+
+
+def _mix_kernel_for(use_kernels: bool, n_nodes: int) -> bool:
+    """Route an (N, N)-block mix to its Pallas kernel: when kernels are on
+    and the block fits VMEM (``repro.kernels.ops.mix_block_fits``)."""
+    if not use_kernels:
+        return False
+    from repro.kernels import ops as kops
+
+    return kops.mix_block_fits(n_nodes)
 
 
 def gossip_dense(state: PushSumState, w: jnp.ndarray, *,
@@ -150,7 +170,7 @@ def gossip_dense(state: PushSumState, w: jnp.ndarray, *,
     see :func:`gossip_circulant`.
     """
     with phase(PHASE_PUSHSUM_MIX):
-        if use_kernels:
+        if _mix_kernel_for(use_kernels, w.shape[0]):
             from repro.kernels import ops as kops
 
             s_new = jax.tree_util.tree_map(lambda x: kops.pushsum_mix(w, x),
@@ -203,7 +223,7 @@ def gossip_sparse(
     stay on the jnp path — too small to tile.
     """
     with phase(PHASE_PUSHSUM_MIX):
-        if use_kernels:
+        if _mix_kernel_for(use_kernels, idx.shape[0]):
             from repro.kernels import ops as kops
 
             s_new = jax.tree_util.tree_map(
@@ -269,7 +289,7 @@ def gossip_packed(
                 g = wire[sparse_idx]  # (N, K, d_pad) bf16
                 s_new = jnp.einsum("nk,nkd->nd", sparse_vals, g,
                                    preferred_element_type=jnp.float32)
-            elif use_kernels:
+            elif _mix_kernel_for(use_kernels, sparse_idx.shape[0]):
                 from repro.kernels import ops as kops
 
                 s_new = kops.pushsum_mix_sparse(sparse_idx, sparse_vals,
@@ -290,7 +310,7 @@ def gossip_packed(
             # fp32 result.
             s_new = jnp.einsum("ij,jd->id", w, wire,
                                preferred_element_type=jnp.float32)
-        elif use_kernels:
+        elif _mix_kernel_for(use_kernels, w.shape[0]):
             from repro.kernels import ops as kops
 
             s_new = kops.pushsum_mix(w, wire)

@@ -15,28 +15,6 @@ import jax.numpy as jnp
 
 PyTree = Any
 
-# The protocol pins its noise draws and wire messages with
-# lax.optimization_barrier (see repro.core.privacy / repro.core.dpps), and
-# the audit battery vmaps whole protocol runs over attack trials. The jax
-# pinned in this container ships no batching rule for the barrier
-# primitive (added upstream later); register the trivial elementwise rule
-# — barrier every batched operand, keep the batch dims — so barriers work
-# under vmap. Guarded: on jax versions that moved these private internals
-# the upstream rule exists and the shim degrades to a no-op.
-try:
-    from jax._src.lax import lax as _lax_internal
-    from jax.interpreters import batching as _batching
-
-    if (_lax_internal.optimization_barrier_p
-            not in _batching.primitive_batchers):
-        def _optimization_barrier_batcher(args, dims):
-            return _lax_internal.optimization_barrier_p.bind(*args), dims
-
-        _batching.primitive_batchers[_lax_internal.optimization_barrier_p] = (
-            _optimization_barrier_batcher)
-except (ImportError, AttributeError):  # pragma: no cover - newer jax
-    pass
-
 __all__ = [
     "tree_l1_norm_per_node",
     "tree_l2_norm_sq_per_node",

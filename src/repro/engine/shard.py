@@ -54,12 +54,11 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.dpps import DPPSConfig, DPPSState, NodeOps
 from repro.core.partpsp import PartPSPConfig, PartPSPState
-from repro.core.pushsum import PushSumState, sparse_mix
+from repro.core.pushsum import MIX_PRECISION, PushSumState, sparse_mix
 from repro.core.sensitivity import SensitivityState
 from repro.engine import rounds as _rounds
 from repro.engine.plan import ProtocolPlan
@@ -187,7 +186,8 @@ def sharded_gossip_builder(plan: ProtocolPlan, axis_name: str, n_shards: int):
             block = x.shape[0]
             row0 = lax.axis_index(axis_name) * block
             w_rows = lax.dynamic_slice_in_dim(w, row0, block, axis=0)
-            return jnp.einsum("ij,j...->i...", w_rows.astype(x.dtype), full)
+            return jnp.einsum("ij,j...->i...", w_rows.astype(x.dtype), full,
+                              precision=MIX_PRECISION)
 
         def gossip_fn(push: PushSumState) -> PushSumState:
             s_new = jax.tree_util.tree_map(mix_leaf, push.s)
@@ -291,11 +291,11 @@ def shard_run_dpps(
 
     state_specs = _dpps_state_specs(state, axis_name)
     eps_specs = jax.tree_util.tree_map(_seq_spec(axis_name), eps_seq)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(state_specs, eps_specs, P()),
         out_specs=(state_specs, P(None)),
-        check_rep=False)
+        check_vma=False)
     return sharded(state, eps_seq, key)
 
 
@@ -332,9 +332,9 @@ def shard_run_partpsp(
 
     state_specs = _partpsp_state_specs(state, axis_name)
     batch_specs = jax.tree_util.tree_map(_seq_spec(axis_name), batches)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(state_specs, batch_specs, P()),
         out_specs=(state_specs, P(None)),
-        check_rep=False)
+        check_vma=False)
     return sharded(state, batches, key)

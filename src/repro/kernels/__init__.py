@@ -17,8 +17,14 @@ wrappers; ref.py pure-jnp oracles):
                     forward (targets the memory-bound 32k prefill rows in
                     EXPERIMENTS.md SRoofline; O(S*D) HBM traffic vs O(S^2))
 
-TPU PRNG note: on real TPUs the bits would come from pltpu.prng_random_bits
-inside the kernel; CPU interpret mode (this container's validation path)
-cannot lower that primitive, so bits are generated with jax.random.bits and
-passed in — the fusion structure (single pass over d_s) is unchanged.
+TPU PRNG note: on real TPUs the bits could come from pltpu.prng_random_bits
+inside the kernel; CPU interpret mode (the test path) cannot lower that
+primitive, so bits are generated with jax.random.bits and passed in — the
+fusion structure (single pass over d_s) is unchanged.
+
+Mosaic constraints the kernels are written to (tests/test_tpu_compile.py
+compiles each one for a v5e chip): per-grid-step partial sums leave as
+lane-aligned (8, 128) blocks and are summed outside the kernel; scalars
+ride in 2-D SMEM operands; bits become floats through int32. Interpret mode
+is chosen only by ``ops.default_interpret`` (from the platform).
 """

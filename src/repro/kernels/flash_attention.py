@@ -42,15 +42,14 @@ def _kernel(q_ref, k_ref, v_ref, spec_ref, o_ref, acc_ref, m_ref, l_ref,
     q = q_ref[0].astype(jnp.float32)          # (BQ, D)
     k = k_ref[0].astype(jnp.float32)          # (BK, D)
     v = v_ref[0].astype(jnp.float32)          # (BK, D)
-    scale = spec_ref[0]
-    window = spec_ref[1]                       # < 0 means global
+    scale = spec_ref[0, 0]
+    window = spec_ref[0, 1]                    # < 0 means global
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (BQ, BK)
 
     q_pos = iq * BQ + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 0)
     k_pos = ik * BK + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 1)
-    mask = q_pos >= k_pos
-    mask = mask & jnp.where(window < 0, True, (q_pos - k_pos) < window)
+    mask = (q_pos >= k_pos) & ((window < 0) | ((q_pos - k_pos) < window))
     s = jnp.where(mask, s, _NEG_INF)
 
     m_prev = m_ref[...]                        # (BQ,)
@@ -71,7 +70,7 @@ def _kernel(q_ref, k_ref, v_ref, spec_ref, o_ref, acc_ref, m_ref, l_ref,
 @functools.partial(jax.jit, static_argnames=("group", "window", "interpret"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     group: int = 1, window: int | None = None,
-                    window_dynamic=None, interpret: bool = True) -> jnp.ndarray:
+                    window_dynamic=None, interpret: bool) -> jnp.ndarray:
     """q: (H, S, D); k, v: (H // group, S, D). Causal; optional sliding
     window (static ``window`` or traced ``window_dynamic``; < 0 == global).
     S must be a multiple of BQ (pad upstream). Returns (H, S, D)."""
@@ -85,7 +84,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         win = jnp.asarray(window_dynamic, jnp.float32)
     else:
         win = jnp.asarray(-1.0 if window is None else float(window), jnp.float32)
-    spec = jnp.stack([jnp.asarray(scale, jnp.float32), win])
+    spec = jnp.stack([jnp.asarray(scale, jnp.float32), win]).reshape(1, 2)
 
     kernel = functools.partial(_kernel, nk=nk, group=group)
     return pl.pallas_call(
@@ -96,7 +95,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pl.BlockSpec((1, BQ, d), lambda ih, iq, ik: (ih, iq, 0)),
             pl.BlockSpec((1, BK, d), lambda ih, iq, ik, g=group: (ih // g, ik, 0)),
             pl.BlockSpec((1, BK, d), lambda ih, iq, ik, g=group: (ih // g, ik, 0)),
-            pl.BlockSpec((2,), lambda ih, iq, ik: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),     # (scale, window)
         ],
         out_specs=pl.BlockSpec((1, BQ, d), lambda ih, iq, ik: (ih, iq, 0)),
         scratch_shapes=[

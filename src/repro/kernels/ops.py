@@ -2,8 +2,10 @@
 kernels -> unpadded results. The node-stacked protocol state vmaps over the
 leading node axis (pallas_call is vmappable, including interpret mode).
 
-``interpret`` defaults to True off-TPU so the same call sites validate on
-CPU and compile to Mosaic on TPU.
+The kernels take ``interpret`` with no default; these wrappers are where it
+is decided, by :func:`default_interpret` from the platform: Mosaic on TPU,
+the Pallas interpreter elsewhere. :func:`mix_block_fits` decides from N
+whether the (N, N)-block mixing kernels fit VMEM at all.
 """
 from __future__ import annotations
 
@@ -24,6 +26,7 @@ from repro.kernels.spmm import spmm as _spmm_kernel
 
 __all__ = [
     "default_interpret",
+    "mix_block_fits",
     "laplace_noise_tree",
     "dpps_perturb_tree",
     "dpps_perturb_packed",
@@ -36,8 +39,26 @@ __all__ = [
 _TILE = TILE_ROWS * LANE  # elements per tile
 
 
+# The TPU compiler's default scoped VMEM limit (v5e): what one kernel's
+# blocks may hold at once.
+_SCOPED_VMEM_BYTES = 16 << 20
+
+
 def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def mix_block_fits(n_nodes: int) -> bool:
+    """Whether ``pushsum_mix`` / ``pushsum_mix_sparse`` fit VMEM at N nodes.
+
+    Both hold an (N, N) f32 weight block (SpMM builds it in VMEM from the
+    edge list, with a temporary of the same size) next to double-buffered
+    (N, TILE_D) input and output tiles. Above this bound the callers in
+    ``repro.core.pushsum`` run the jnp dot / gather path instead.
+    """
+    block = 4 * n_nodes * n_nodes
+    tiles = 4 * 2 * 2 * n_nodes * TILE_D
+    return 2 * block + tiles <= _SCOPED_VMEM_BYTES
 
 
 def _pad_flat(x: jnp.ndarray) -> tuple[jnp.ndarray, int]:

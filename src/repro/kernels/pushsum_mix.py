@@ -25,11 +25,12 @@ def _kernel(w_ref, x_ref, o_ref):
     o_ref[...] = jnp.dot(
         w_ref[...], x_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,  # f32 on the MXU, not one bf16 pass
     ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def pushsum_mix(w: jnp.ndarray, x: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def pushsum_mix(w: jnp.ndarray, x: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     """w: (N, N) f32; x: (N, D) with D a multiple of TILE_D (pad upstream)."""
     n, d = x.shape
     assert w.shape == (n, n)

@@ -37,12 +37,13 @@ def _kernel(idx_ref, vals_ref, x_ref, o_ref):
     o_ref[...] = jnp.dot(
         w, x_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,  # f32 on the MXU, not one bf16 pass
     ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def spmm(idx: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray, *,
-         interpret: bool = True) -> jnp.ndarray:
+         interpret: bool) -> jnp.ndarray:
     """idx/vals: (N, K) padded CSR; x: (N, D), D a multiple of TILE_D."""
     n, d = x.shape
     assert idx.shape == vals.shape and idx.shape[0] == n, (idx.shape, x.shape)

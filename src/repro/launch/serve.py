@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.api import Session
 from repro.checkpoint import load_checkpoint
 from repro.configs import ARCH_NAMES, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Transformer
 
 
@@ -38,6 +39,7 @@ def main() -> None:
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_config(args.arch)
     cfg = arch.smoke if args.reduced else arch.model

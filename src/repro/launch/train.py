@@ -1,8 +1,11 @@
 """PartPSP training driver.
 
 Runs the full decentralized DP training loop on whatever devices exist:
-on this CPU container it runs reduced configs end-to-end (the examples use
-it); on a real fleet the same code paths run on the production mesh.
+on a CPU it runs reduced configs end-to-end (the examples use it); on one
+TPU chip it trains full-width configs (``chip_smoke.py`` drives
+xlstm-125m); on a real fleet the same code paths run on the production
+mesh. Pallas kernels route by platform unless --use-kernels /
+--no-use-kernels says otherwise.
 
     PYTHONPATH=src python -m repro.launch.train --arch llama3.2-1b \
         --reduced --nodes 8 --steps 50 --algorithm partpsp
@@ -51,6 +54,7 @@ from repro.api import (
     LedgerHook,
     MetricsHook,
     PrivacySpec,
+    RunReport,
     Session,
     add_delay_arguments,
     add_fault_arguments,
@@ -65,6 +69,7 @@ from repro.api import (
 )
 from repro.configs import ARCH_NAMES, get_config
 from repro.data import NodeShardedLoader, SyntheticLMStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Transformer
 
 
@@ -77,7 +82,8 @@ def build_session(arch_name: str, *, reduced: bool, n_nodes: int,
                   algorithm: str, b: float, gamma_n: float, gamma_l: float,
                   gamma_s: float, clip: float, topology, degree: int = 2,
                   sync_interval: int = 5, schedule: str = "dense",
-                  use_kernels: bool = False, seed: int = 0, chunk: int = 50,
+                  use_kernels: bool | None = None, seed: int = 0,
+                  chunk: int = 50,
                   packed: bool = True, wire_dtype: str = "f32", faults=None,
                   delays=None, wire=None):
     """Arch-specific assembly -> one protocol session (the front door).
@@ -144,7 +150,7 @@ def build_engine_trainer(arch_name: str, *, chunk: int = 50,
             session.segment_runner(), session.plan)
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> RunReport:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=ARCH_NAMES, default="llama3.2-1b")
     ap.add_argument("--reduced", action="store_true",
@@ -166,7 +172,10 @@ def main() -> None:
     ap.add_argument("--sync-interval", type=int, default=5)
     ap.add_argument("--schedule", choices=("dense", "circulant", "sparse"),
                     default="dense")
-    ap.add_argument("--use-kernels", action="store_true")
+    ap.add_argument("--use-kernels", action=argparse.BooleanOptionalAction,
+                    default=None,
+                    help="Pallas kernels on/off (default: on iff the "
+                         "backend is TPU)")
     ap.add_argument("--driver", choices=("engine", "loop"), default="engine",
                     help="scan-compiled engine segments vs per-round loop")
     add_protocol_arguments(ap)
@@ -180,7 +189,8 @@ def main() -> None:
                     help="total epsilon ceiling for the run")
     ap.add_argument("--strict-budget", action="store_true",
                     help="abort training once --privacy-budget is exceeded")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
     validate_protocol_args(ap, args)
     topo = topology_from_args(ap, args, args.nodes)
     faults = faults_from_args(ap, args, n_nodes=args.nodes)
@@ -279,6 +289,7 @@ def main() -> None:
             print("checkpoint NOT written (over budget):", args.checkpoint)
         raise SystemExit(
             "aborted: privacy budget exhausted (--strict-budget)")
+    return report
 
 
 if __name__ == "__main__":

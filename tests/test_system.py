@@ -7,7 +7,8 @@ import pytest
 
 from repro.core.partpsp import privacy_summary
 from repro.data import NodeShardedLoader, SyntheticLMStream
-from repro.launch.train import build_trainer
+from repro.launch import train as train_cli
+from repro.launch.train import build_session, build_trainer
 from repro.optim import adamw, global_norm, sgd
 
 
@@ -72,6 +73,31 @@ def test_end_to_end_moe():
 def test_end_to_end_zamba():
     cfg, _, _, hist = _train(arch="zamba2-7b", steps=3)
     assert all(np.isfinite(h["loss_mean"]) for h in hist)
+
+
+@pytest.mark.parametrize("use_kernels", [None, True, False])
+def test_build_session_routes_kernels_by_platform(use_kernels):
+    """The trainer pins nothing: None leaves the choice to the plan (on iff
+    the backend is TPU), an explicit value wins."""
+    _, _, session = build_session(
+        "xlstm-125m", reduced=True, n_nodes=2, algorithm="partpsp", b=3.0,
+        gamma_n=1e-6, gamma_l=0.05, gamma_s=0.05, clip=100.0,
+        topology="dout", use_kernels=use_kernels)
+    want = (jax.default_backend() == "tpu" if use_kernels is None
+            else use_kernels)
+    assert session.plan.use_kernels is want
+
+
+def test_train_cli_returns_its_report(monkeypatch, tmp_path):
+    # a placed cache dir: the entry point must not set one of its own
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    report = train_cli.main(["--arch", "xlstm-125m", "--reduced",
+                             "--nodes", "2", "--steps", "2", "--chunk", "2",
+                             "--gamma-n", "1e-6", "--log-every", "10",
+                             "--no-use-kernels"])
+    losses = np.asarray(report.trajectory["loss_mean"])
+    assert report.rounds == 2 and losses.shape == (2,)
+    assert np.isfinite(losses).all()
 
 
 # ---------------------------------------------------------------------------

@@ -75,7 +75,8 @@ class RunReport:
                      (pure-DP linear composition; sync rounds excluded).
       wire_bytes     estimated protocol payload traffic (module docstring).
       compile_s      wall seconds of the *first* segment — tracing + XLA
-                     compilation + its first dispatch (synced).
+                     compilation + its execution (synced) — when the call
+                     compiled; 0 when it compiled nothing (no sync made).
       run_s          wall seconds of everything after: the steady-state
                      segments plus host-side hook consumption. Per-round
                      timing figures should use this (see
@@ -93,6 +94,15 @@ class RunReport:
                      above stays the *nominal* plan estimate;
                      ``network.effective_bytes`` is what actually crossed
                      the wire.
+      counts         what the call asked of the runtime:
+                     ``dispatches`` (compiled segments enqueued),
+                     ``host_syncs`` (times the host waited on the device:
+                     the first-segment sync of a compiling call, every
+                     per-segment sync a span hook asks for, each
+                     trajectory leaf read back) and ``compiles`` (the
+                     change over the call in the process-wide count of
+                     lowerings + backend compiles). The call's
+                     ``repro.api.report`` host span carries them as stats.
     """
 
     state: Any
@@ -105,6 +115,7 @@ class RunReport:
     aborted: bool = False
     abort_reason: str | None = None
     network: Any = None
+    counts: dict[str, int] = dataclasses.field(default_factory=dict)
 
     @property
     def wall_clock(self) -> float:
@@ -120,6 +131,7 @@ class RunReport:
             "run_s": round(self.run_s, 3),
             "wall_clock_s": round(self.wall_clock, 3),
             "aborted": self.aborted,
+            "counts": dict(self.counts),
         }
         if self.network is not None:
             out["network"] = self.network.summary()

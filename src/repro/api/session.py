@@ -80,6 +80,20 @@ from repro.engine import (
     run_segments,
     stack_rounds,
 )
+from repro.obs.trace import (
+    SPAN_API_CONSENSUS,
+    SPAN_API_COPY_STATE,
+    SPAN_API_HOOKS,
+    SPAN_API_REPORT,
+    SPAN_API_RUN,
+    SPAN_API_STATE_INIT,
+    SPAN_API_TRAIN,
+    SPAN_API_WAIT,
+    SPAN_ENGINE_DISPATCH,
+    SPAN_ENGINE_INPUTS,
+    compile_count,
+    span,
+)
 
 __all__ = ["PrivacySpec", "ProtocolSession", "Session"]
 
@@ -361,7 +375,8 @@ class ProtocolSession:
 
     def consensus(self, state: DPPSState) -> PyTree:
         """Protocol output s-bar (Alg. 1 Output) from a consensus run."""
-        return _dpps_consensus(state)
+        with span(SPAN_API_CONSENSUS, call=self._calls):
+            return _dpps_consensus(state)
 
     def consensus_view(self, state: PartPSPState, node: int = 0) -> PyTree:
         """Evaluation/serving params: network-average shared (s-bar) merged
@@ -427,6 +442,16 @@ class ProtocolSession:
     # -- drivers -------------------------------------------------------------
 
     @property
+    def _calls(self) -> int:
+        """How many run()/train() calls this session has begun: the ``call``
+        id every host span of the latest call carries."""
+        return self.__dict__.get("_n_calls", 0)
+
+    def _next_call(self) -> int:
+        object.__setattr__(self, "_n_calls", self._calls + 1)
+        return self._calls
+
+    @property
     def _protected(self) -> bool:
         return bool(self.cfg is not None and self.cfg.noise
                     and self.cfg.gamma_n > 0)
@@ -447,7 +472,8 @@ class ProtocolSession:
                           protected=self._protected, d_s=d_s)
 
     def _drive(self, segments: Iterator, hooks: Sequence[RoundHook],
-               d_s: int, start: int = 0) -> RunReport:
+               d_s: int, start: int, *, call: int,
+               compiles0: int) -> RunReport:
         """Shared host loop: consume hooks per segment, assemble the report.
 
         A strict hook aborts between segments (any :class:`RunAbort` —
@@ -456,19 +482,27 @@ class ProtocolSession:
         rounds *this* call executed — resumed runs (``start > 0``) never
         re-count the prefix.
 
-        Wall-clock split: the first segment's wall time (which includes
-        tracing + XLA compilation of the scan) is reported as
-        ``compile_s``; everything after is steady-state ``run_s``.
+        Wall-clock split: when the call compiled (the process-wide
+        compile count grew since ``compiles0``, taken as the call began),
+        the first segment is synced and its wall time — tracing + XLA
+        compilation of the scan + its execution — is reported as
+        ``compile_s``; everything after is steady-state ``run_s``. A call
+        that compiled nothing reports ``compile_s`` 0 and makes no sync
+        before the trajectory is read back.
 
         Hooks exposing a ``segment_span`` method (duck-typed — the
         :class:`repro.obs.timeline.TimelineHook` seam) get per-segment
         host timing: with one attached every segment is synced before its
         boundary is stamped, so execute vs hook-consume spans are real
-        device time. Without one, only the first segment syncs — the
-        hookless path is unchanged.
+        device time.
+
+        Every wait on the device is a ``repro.api.wait`` span and counts
+        in ``RunReport.counts["host_syncs"]``.
         """
         t_start = time.time()
         compile_s = 0.0
+        compiled = False
+        syncs = 0
         trajs: list[dict[str, Any]] = []
         state = None
         done = start
@@ -476,66 +510,86 @@ class ProtocolSession:
         reason = None
         span_hooks = [h for h in hooks if hasattr(h, "segment_span")]
         seg_start = t_start
+
+        def wait(fetch, x):
+            nonlocal syncs
+            syncs += 1
+            with span(SPAN_API_WAIT, call=call):
+                return fetch(x)
+
         try:
             for t0, n, state, traj in segments:
                 done = t0 + n
                 first = not trajs
+                if first:
+                    compiled = compile_count() > compiles0
                 exec_end = None
-                if first or span_hooks:
-                    # End of the first segment = compile + first dispatch;
-                    # sync so the boundary is real device time, not the
-                    # async dispatch returning early. Span hooks need the
-                    # same sync on every segment.
-                    jax.block_until_ready(traj)
+                if (first and compiled) or span_hooks:
+                    # End of the compiling first segment = compile + first
+                    # execution; sync so the boundary is real device time,
+                    # not the async dispatch returning early. Span hooks
+                    # need the same sync on every segment.
+                    wait(jax.block_until_ready, traj)
                     exec_end = time.time()
-                    if first:
+                    if first and compiled:
                         compile_s = exec_end - t_start
                 trajs.append(traj)
-                for h in hooks:
-                    h.consume(traj, t0=t0)
+                if hooks:
+                    with span(SPAN_API_HOOKS, call=call):
+                        for h in hooks:
+                            h.consume(traj, t0=t0)
                 if span_hooks:
                     consume_end = time.time()
                     for h in span_hooks:
                         h.segment_span(t0=t0, n=n, start=seg_start,
                                        execute_end=exec_end,
                                        consume_end=consume_end,
-                                       compiled=first)
+                                       compiled=first and compiled)
                     seg_start = consume_end
         except RunAbort as e:
             aborted = True
             reason = str(e)
         finally:
+            if hooks:
+                with span(SPAN_API_HOOKS, call=call):
+                    for h in hooks:
+                        h.finish()
+        with span(SPAN_API_REPORT, call=call) as report_span:
+            trajectory = {}
+            if trajs:
+                keys = trajs[0].keys()
+                trajectory = {k: np.concatenate([wait(np.asarray, t[k])
+                                                 for t in trajs])
+                              for k in keys}
+            executed = done - start
+            # Any hook exposing network_stats() (repro.net.stats.
+            # NetworkStatsHook — duck-typed so repro.api never imports
+            # repro.net) contributes the realized-network record.
+            network = None
             for h in hooks:
-                h.finish()
-        trajectory = {}
-        if trajs:
-            keys = trajs[0].keys()
-            trajectory = {k: np.concatenate([np.asarray(t[k]) for t in trajs])
-                          for k in keys}
-        executed = done - start
-        # Any hook exposing network_stats() (repro.net.stats.
-        # NetworkStatsHook — duck-typed so repro.api never imports
-        # repro.net) contributes the realized-network record.
-        network = None
-        for h in hooks:
-            stats_fn = getattr(h, "network_stats", None)
-            if stats_fn is not None:
-                network = stats_fn()
-        report = RunReport(
-            state=state, trajectory=trajectory, rounds=executed,
-            epsilon_spent=self.epsilon_spent(executed, start=start),
-            wire_bytes=estimate_wire_bytes(self.plan, self.n_nodes, d_s,
-                                           executed),
-            compile_s=compile_s,
-            run_s=time.time() - t_start - compile_s, aborted=aborted,
-            abort_reason=reason, network=network)
+                stats_fn = getattr(h, "network_stats", None)
+                if stats_fn is not None:
+                    network = stats_fn()
+            counts = {"dispatches": len(trajs), "host_syncs": syncs,
+                      "compiles": compile_count() - compiles0}
+            report_span.set_metadata(**counts)
+            report = RunReport(
+                state=state, trajectory=trajectory, rounds=executed,
+                epsilon_spent=self.epsilon_spent(executed, start=start),
+                wire_bytes=estimate_wire_bytes(self.plan, self.n_nodes, d_s,
+                                               executed),
+                compile_s=compile_s,
+                run_s=time.time() - t_start - compile_s, aborted=aborted,
+                abort_reason=reason, network=network, counts=counts)
         # Run-level publication (run.compile_s / run.run_s gauges, the
         # timeline artifact) — after the report exists, abort included.
         # getattr: duck-typed hooks predating the base class keep working.
-        for h in hooks:
-            finish_run = getattr(h, "finish_run", None)
-            if finish_run is not None:
-                finish_run(report)
+        if hooks:
+            with span(SPAN_API_HOOKS, call=call):
+                for h in hooks:
+                    finish_run = getattr(h, "finish_run", None)
+                    if finish_run is not None:
+                        finish_run(report)
         return report
 
     def run(
@@ -558,31 +612,44 @@ class ProtocolSession:
         compiled segments; hooks consume at every boundary.
         """
         self._require_protocol()
-        if state is None:
-            if values is None:
-                raise ValueError("run() needs values= (fresh) or state=")
-            state = self.consensus_state(values)
-        state = _own_buffers(state)
-        key = self.base_key if key is None else key
-        hooks = tuple(hooks)
-        d_s = sum(int(np.prod(x.shape[1:])) if x.ndim > 1 else 1
-                  for x in jax.tree_util.tree_leaves(state.push.s))
-        for h in hooks:
-            h.prepare(self._context(rounds, "dpps", d_s))
-        run_chunk = self.consensus_runner(hooks)
-        chunk = self.plan.chunk
+        call = self._next_call()
+        with span(SPAN_API_RUN, call=call):
+            compiles0 = compile_count()
+            if state is None:
+                if values is None:
+                    raise ValueError("run() needs values= (fresh) or state=")
+                with span(SPAN_API_STATE_INIT, call=call):
+                    state = self.consensus_state(values)
+            with span(SPAN_API_COPY_STATE, call=call):
+                state = _own_buffers(state)
+            key = self.base_key if key is None else key
+            hooks = tuple(hooks)
+            d_s = sum(int(np.prod(x.shape[1:])) if x.ndim > 1 else 1
+                      for x in jax.tree_util.tree_leaves(state.push.s))
+            if hooks:
+                with span(SPAN_API_HOOKS, call=call):
+                    for h in hooks:
+                        h.prepare(self._context(rounds, "dpps", d_s))
+            run_chunk = self.consensus_runner(hooks)
+            chunk = self.plan.chunk
 
-        def segments():
-            st = state
-            for t0 in range(start, start + rounds, chunk):
-                n = min(chunk, start + rounds - t0)
-                if eps_at is None:
-                    st, traj = run_chunk(st, None, key, rounds=n)
-                else:
-                    st, traj = run_chunk(st, stack_rounds(eps_at, t0, n), key)
-                yield t0, n, st, traj
+            def segments():
+                st = state
+                for t0 in range(start, start + rounds, chunk):
+                    n = min(chunk, start + rounds - t0)
+                    ids = dict(call=call, t0=t0, rounds=n)
+                    if eps_at is None:
+                        with span(SPAN_ENGINE_DISPATCH, **ids):
+                            st, traj = run_chunk(st, None, key, rounds=n)
+                    else:
+                        with span(SPAN_ENGINE_INPUTS, **ids):
+                            eps = stack_rounds(eps_at, t0, n)
+                        with span(SPAN_ENGINE_DISPATCH, **ids):
+                            st, traj = run_chunk(st, eps, key)
+                    yield t0, n, st, traj
 
-        return self._drive(segments(), hooks, d_s, start)
+            return self._drive(segments(), hooks, d_s, start, call=call,
+                               compiles0=compiles0)
 
     def train(
         self,
@@ -608,25 +675,34 @@ class ProtocolSession:
         self._require_protocol()
         if driver not in ("engine", "loop"):
             raise ValueError(f"unknown driver {driver!r}")
-        if state is None:
-            state = self.train_state()
-        state = _own_buffers(state)
-        key = self.base_key if key is None else key
-        hooks = tuple(hooks)
-        for h in hooks:
-            h.prepare(self._context(rounds, self.algorithm,
-                                    self.partition.d_shared()))
-        if driver == "engine":
-            run_chunk = self.segment_runner(hooks)
-            segments = run_segments(run_chunk, state, batch_at, key,
-                                    steps=rounds, chunk=self.plan.chunk,
-                                    start=start)
-        else:
-            segments = self._loop_segments(state, batch_at, key, rounds,
-                                           start, hooks)
-        return self._drive(segments, hooks, self.partition.d_shared(), start)
+        call = self._next_call()
+        with span(SPAN_API_TRAIN, call=call):
+            compiles0 = compile_count()
+            if state is None:
+                with span(SPAN_API_STATE_INIT, call=call):
+                    state = self.train_state()
+            with span(SPAN_API_COPY_STATE, call=call):
+                state = _own_buffers(state)
+            key = self.base_key if key is None else key
+            hooks = tuple(hooks)
+            if hooks:
+                with span(SPAN_API_HOOKS, call=call):
+                    for h in hooks:
+                        h.prepare(self._context(rounds, self.algorithm,
+                                                self.partition.d_shared()))
+            if driver == "engine":
+                run_chunk = self.segment_runner(hooks)
+                segments = run_segments(run_chunk, state, batch_at, key,
+                                        steps=rounds, chunk=self.plan.chunk,
+                                        start=start, call=call)
+            else:
+                segments = self._loop_segments(state, batch_at, key, rounds,
+                                               start, hooks, call)
+            return self._drive(segments, hooks, self.partition.d_shared(),
+                               start, call=call, compiles0=compiles0)
 
-    def _loop_segments(self, state, batch_at, key, rounds, start, hooks):
+    def _loop_segments(self, state, batch_at, key, rounds, start, hooks,
+                       call):
         """Per-round reference driver as a segment stream (T=1 segments).
 
         Runs the pytree path (no packed layout — the loop is the oracle)
@@ -729,9 +805,13 @@ class ProtocolSession:
             state = state._replace(dpps=self._attach_mail(state.dpps))
 
         for t in range(start, start + rounds):
-            mix, net = mix_for(t)
-            state, m = step(state, batch_at(t), jax.random.fold_in(key, t),
-                            **mix)
+            ids = dict(call=call, t0=t, rounds=1)
+            with span(SPAN_ENGINE_INPUTS, **ids):
+                batch = batch_at(t)
+                mix, net = mix_for(t)
+            with span(SPAN_ENGINE_DISPATCH, **ids):
+                state, m = step(state, batch, jax.random.fold_in(key, t),
+                                **mix)
             if net is not None:
                 m = dict(m, **net)
             rows = capture_rows(m, hooks)
@@ -759,9 +839,10 @@ class ProtocolSession:
         of the execute. The trace's per-op times are joined against the
         compiled module's ``op_name`` metadata — where the
         :func:`repro.obs.phase` annotations survive — into a per-phase
-        device-time breakdown (:class:`repro.obs.ProfileReport`). When the
-        xplane protobuf bindings are unavailable the breakdown degrades to
-        empty with a ``note``; the wall-clock split always works.
+        device-time breakdown (:class:`repro.obs.ProfileReport`), the
+        trace read with ``jax.profiler.ProfileData``. When the trace holds
+        no op execution the breakdown degrades to empty with a ``note``;
+        the wall-clock split always works.
 
         ``hooks`` are attached trace-time only (their captures shape the
         profiled program exactly as in :meth:`run`/:meth:`train`); their
@@ -876,7 +957,9 @@ class ProtocolSession:
         if push is not None:
             d_s = sum(int(np.prod(x.shape[1:])) if x.ndim > 1 else 1
                       for x in jax.tree_util.tree_leaves(push.s))
-        chunk = getattr(self.plan, "chunk", 0) or 0
+        # run_s leaves out the first segment only when the call compiled
+        chunk = (getattr(self.plan, "chunk", 0) or 0) if report.compile_s \
+            else 0
         steady = max(report.rounds - chunk, 0)
         scale = {
             "n_nodes": self.n_nodes, "d_s": d_s,

@@ -85,7 +85,10 @@ from repro.obs.trace import (
     PHASE_FAULTS,
     PHASE_PACK,
     PHASE_UNPACK,
+    SPAN_ENGINE_DISPATCH,
+    SPAN_ENGINE_INPUTS,
     phase,
+    span,
 )
 
 __all__ = ["run_dpps", "run_partpsp", "run_decode", "run_segments",
@@ -138,18 +141,25 @@ def stack_rounds(make_round: Callable[[int], PyTree], t0: int, n: int) -> PyTree
 
 
 def run_segments(run_chunk: Callable, state, batch_at: Callable[[int], PyTree],
-                 key: jax.Array, *, steps: int, chunk: int, start: int = 0):
+                 key: jax.Array, *, steps: int, chunk: int, start: int = 0,
+                 call: int = 0):
     """Drive a jitted segment runner over ``steps`` rounds in ``chunk``s.
 
     Yields ``(t0, n, state, traj)`` after each segment: the segment's first
     absolute round, its length (the final segment may be shorter), the
     advanced state, and the per-round metric trajectory. Host work (batch
     stacking via ``batch_at``) happens between dispatches, and checkpoints
-    naturally land on segment boundaries.
+    naturally land on segment boundaries. Each segment's input stacking
+    and dispatch are ``repro.engine.inputs`` / ``repro.engine.dispatch``
+    host spans, stamped with ``call`` (the caller's call id), ``t0`` and
+    ``rounds``.
     """
     for t0 in range(start, start + steps, chunk):
         n = min(chunk, start + steps - t0)
-        state, traj = run_chunk(state, stack_rounds(batch_at, t0, n), key)
+        with span(SPAN_ENGINE_INPUTS, call=call, t0=t0, rounds=n):
+            batches = stack_rounds(batch_at, t0, n)
+        with span(SPAN_ENGINE_DISPATCH, call=call, t0=t0, rounds=n):
+            state, traj = run_chunk(state, batches, key)
         yield t0, n, state, traj
 
 

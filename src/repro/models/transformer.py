@@ -12,6 +12,10 @@ Key structural decisions (see DESIGN.md):
 * Cross-entropy is computed in vocab-preserving sequence chunks under
   ``jax.checkpoint`` — materializing full (B, S, V) logits for a 262k vocab
   would be hundreds of GB/device.
+* Block boundaries carry ``repro.obs`` phase scopes (``model_embed``,
+  ``model_attn``, ``model_mlp``, ``model_moe``, ``model_mlstm``,
+  ``model_slstm``, ``model_mamba2``, ``model_head``): metadata only, they
+  name each block's ops in the compiled program's ``op_name``.
 * ``param_pspecs`` returns a PartitionSpec tree aligned with params:
   head/ffn/expert dims shard over the mesh "model" axis; the launcher
   prepends the gossip axes for the node-stacked training state.
@@ -49,6 +53,17 @@ from repro.models.config import (
 )
 from repro.models.layers import dense_init, init_rms_norm, mlp_apply, mlp_init, rms_norm, rope, softcap
 from repro.models.moe import init_moe, moe_apply
+from repro.obs.trace import (
+    PHASE_MODEL_ATTN,
+    PHASE_MODEL_EMBED,
+    PHASE_MODEL_HEAD,
+    PHASE_MODEL_MAMBA2,
+    PHASE_MODEL_MLP,
+    PHASE_MODEL_MLSTM,
+    PHASE_MODEL_MOE,
+    PHASE_MODEL_SLSTM,
+    phase,
+)
 
 _NEG_INF = -1e30
 
@@ -230,12 +245,16 @@ class _AttnGroupImpl(_GroupImpl):
 
         def body(h, xs):
             lp, window, theta = xs
-            a, k, v = _attn_train(lp["attn"], rms_norm(lp["ln1"], h, cfg.norm_eps),
-                                  positions, cfg, theta, window,
-                                  use_flash=use_flash)
-            h = h + a
-            h = h + mlp_apply(lp["mlp"], rms_norm(lp["ln2"], h, cfg.norm_eps),
-                              cfg.activation)
+            with phase(PHASE_MODEL_ATTN):
+                a, k, v = _attn_train(lp["attn"],
+                                      rms_norm(lp["ln1"], h, cfg.norm_eps),
+                                      positions, cfg, theta, window,
+                                      use_flash=use_flash)
+                h = h + a
+            with phase(PHASE_MODEL_MLP):
+                h = h + mlp_apply(lp["mlp"],
+                                  rms_norm(lp["ln2"], h, cfg.norm_eps),
+                                  cfg.activation)
             ys = (k, v) if collect_cache else None
             return h, ys
 
@@ -375,12 +394,15 @@ class _MoEGroupImpl(_GroupImpl):
                 lp = unit["moe"]
             else:
                 lp = unit
-            a, k, v = _attn_train(lp["attn"], rms_norm(lp["ln1"], h, cfg.norm_eps),
-                                  positions, cfg, theta, window,
-                                  use_flash=use_flash)
-            h = h + a
-            f, aux_l = self._ffn(lp, rms_norm(lp["ln2"], h, cfg.norm_eps))
-            h = h + f
+            with phase(PHASE_MODEL_ATTN):
+                a, k, v = _attn_train(lp["attn"],
+                                      rms_norm(lp["ln1"], h, cfg.norm_eps),
+                                      positions, cfg, theta, window,
+                                      use_flash=use_flash)
+                h = h + a
+            with phase(PHASE_MODEL_MOE):
+                f, aux_l = self._ffn(lp, rms_norm(lp["ln2"], h, cfg.norm_eps))
+                h = h + f
             ys = ((d_cache, k, v) if interleaved else (k, v)) if collect_cache else None
             return (h, aux + aux_l), ys
 
@@ -517,12 +539,16 @@ class _XLSTMGroupImpl(_GroupImpl):
                                       n_heads=cfg.n_heads, state=st)
             return h + y, st_new
 
-        x, m_state = jax.lax.scan(jax.checkpoint(m_body), x,
-                                  (up["mlstm"], state["mlstm"]))
+        with phase(PHASE_MODEL_MLSTM):
+            x, m_state = jax.lax.scan(jax.checkpoint(m_body), x,
+                                      (up["mlstm"], state["mlstm"]))
         sl = up["slstm"]
-        y, s_state = ssm.slstm_seq(sl["cell"], rms_norm(sl["ln"], x, cfg.norm_eps),
-                                   state=state["slstm"])
-        return x + y, {"mlstm": m_state, "slstm": s_state}
+        with phase(PHASE_MODEL_SLSTM):
+            y, s_state = ssm.slstm_seq(sl["cell"],
+                                       rms_norm(sl["ln"], x, cfg.norm_eps),
+                                       state=state["slstm"])
+            x = x + y
+        return x, {"mlstm": m_state, "slstm": s_state}
 
     def train(self, params, x, positions, enc=None, collect_cache=False,
               use_flash=False):
@@ -605,9 +631,12 @@ class _MambaGroupImpl(_GroupImpl):
 
         def body(h, xs):
             lp, st = xs
-            y, st_new = ssm.mamba2_seq(lp["cell"], rms_norm(lp["ln"], h, cfg.norm_eps),
-                                       head_dim=64, state=st)
-            return h + y, (st_new if collect_cache else None)
+            with phase(PHASE_MODEL_MAMBA2):
+                y, st_new = ssm.mamba2_seq(lp["cell"],
+                                           rms_norm(lp["ln"], h, cfg.norm_eps),
+                                           head_dim=64, state=st)
+                h = h + y
+            return h, (st_new if collect_cache else None)
 
         x, ys = jax.lax.scan(jax.checkpoint(body), x, (params, cache0))
         return x, jnp.zeros((), jnp.float32), (ys if collect_cache else None)
@@ -699,15 +728,18 @@ class _ZambaGroupImpl(_GroupImpl):
         def body(h, up):
             h, _, m_cache = self._mamba_unit.train(up, h, positions,
                                                    collect_cache=collect_cache)
-            a, k, v = _attn_train(shared["attn"],
-                                  rms_norm(shared["ln1"], h, cfg.norm_eps),
-                                  positions, cfg,
-                                  jnp.asarray(cfg.rope_theta, jnp.float32),
-                                  jnp.asarray(-1, jnp.int32),
-                                  use_flash=use_flash)
-            h = h + a
-            h = h + mlp_apply(shared["mlp"], rms_norm(shared["ln2"], h, cfg.norm_eps),
-                              cfg.activation)
+            with phase(PHASE_MODEL_ATTN):
+                a, k, v = _attn_train(shared["attn"],
+                                      rms_norm(shared["ln1"], h, cfg.norm_eps),
+                                      positions, cfg,
+                                      jnp.asarray(cfg.rope_theta, jnp.float32),
+                                      jnp.asarray(-1, jnp.int32),
+                                      use_flash=use_flash)
+                h = h + a
+            with phase(PHASE_MODEL_MLP):
+                h = h + mlp_apply(shared["mlp"],
+                                  rms_norm(shared["ln2"], h, cfg.norm_eps),
+                                  cfg.activation)
             ys = (m_cache, k, v) if collect_cache else None
             return h, ys
 
@@ -831,10 +863,13 @@ class _CrossSelfGroupImpl(_GroupImpl):
 
     def _cross(self, up, h, enc):
         cfg = self.cfg
-        y = cross_attention(up["cross"], rms_norm(up["cross_ln"], h, cfg.norm_eps),
-                            enc, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-                            head_dim=cfg.head_dim)
-        return h + y
+        with phase(PHASE_MODEL_ATTN):
+            y = cross_attention(up["cross"],
+                                rms_norm(up["cross_ln"], h, cfg.norm_eps),
+                                enc, n_heads=cfg.n_heads,
+                                n_kv_heads=cfg.n_kv_heads,
+                                head_dim=cfg.head_dim)
+            return h + y
 
     def train(self, params, x, positions, enc=None, collect_cache=False,
               use_flash=False):
@@ -920,13 +955,14 @@ class Transformer:
     # -- forward --------------------------------------------------------------
     def _embed_inputs(self, params, batch):
         cfg = self.cfg
-        if cfg.input_mode == "embeddings":
-            x = batch["embeds"].astype(self.dtype)
-        else:
-            x = params["embed"][batch["tokens"]]
-        if cfg.embed_scale:
-            x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
-        return x
+        with phase(PHASE_MODEL_EMBED):
+            if cfg.input_mode == "embeddings":
+                x = batch["embeds"].astype(self.dtype)
+            else:
+                x = params["embed"][batch["tokens"]]
+            if cfg.embed_scale:
+                x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
+            return x
 
     def _labels(self, batch):
         return batch["labels"] if "labels" in batch else batch["tokens"]
@@ -941,7 +977,8 @@ class Transformer:
             aux = aux + a
             if collect_cache:
                 caches[f"group_{i}"] = c
-        x = rms_norm(params["final_ln"], x, self.cfg.norm_eps)
+        with phase(PHASE_MODEL_HEAD):
+            x = rms_norm(params["final_ln"], x, self.cfg.norm_eps)
         return x, aux, caches
 
     def _head(self, params, x):
@@ -966,41 +1003,42 @@ class Transformer:
         """Mean next-token cross entropy (+ MoE aux), seq-chunked over vocab."""
         cfg = self.cfg
         h, aux = self.forward_train(params, batch)
-        labels = self._labels(batch)
-        # predict token t+1 from hidden t
-        h = h[:, :-1]
-        targets = labels[:, 1:]
-        b, sm1, d = h.shape
-        chunk = min(self.LOSS_CHUNK, sm1)
-        n_chunks = sm1 // chunk
-        rem = sm1 - n_chunks * chunk
+        with phase(PHASE_MODEL_HEAD):
+            labels = self._labels(batch)
+            # predict token t+1 from hidden t
+            h = h[:, :-1]
+            targets = labels[:, 1:]
+            b, sm1, d = h.shape
+            chunk = min(self.LOSS_CHUNK, sm1)
+            n_chunks = sm1 // chunk
+            rem = sm1 - n_chunks * chunk
 
-        head = params["embed"] if cfg.tie_embedding else None
+            head = params["embed"] if cfg.tie_embedding else None
 
-        def chunk_loss(h_c, t_c):
-            logits = self._head(params, h_c)  # (B, c, V) f32
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            picked = jnp.take_along_axis(logits, t_c[..., None], axis=-1)[..., 0]
-            return jnp.sum(lse - picked)
+            def chunk_loss(h_c, t_c):
+                logits = self._head(params, h_c)  # (B, c, V) f32
+                lse = jax.nn.logsumexp(logits, axis=-1)
+                picked = jnp.take_along_axis(logits, t_c[..., None], axis=-1)[..., 0]
+                return jnp.sum(lse - picked)
 
-        chunk_loss = jax.checkpoint(chunk_loss)
+            chunk_loss = jax.checkpoint(chunk_loss)
 
-        total = jnp.zeros((), jnp.float32)
-        if n_chunks > 0:
-            h_chunks = h[:, : n_chunks * chunk].reshape(b, n_chunks, chunk, d)
-            t_chunks = targets[:, : n_chunks * chunk].reshape(b, n_chunks, chunk)
+            total = jnp.zeros((), jnp.float32)
+            if n_chunks > 0:
+                h_chunks = h[:, : n_chunks * chunk].reshape(b, n_chunks, chunk, d)
+                t_chunks = targets[:, : n_chunks * chunk].reshape(b, n_chunks, chunk)
 
-            def body(acc, xs):
-                h_c, t_c = xs
-                return acc + chunk_loss(h_c, t_c), None
+                def body(acc, xs):
+                    h_c, t_c = xs
+                    return acc + chunk_loss(h_c, t_c), None
 
-            total, _ = jax.lax.scan(
-                body, total,
-                (jnp.moveaxis(h_chunks, 1, 0), jnp.moveaxis(t_chunks, 1, 0)))
-        if rem:
-            total = total + chunk_loss(h[:, n_chunks * chunk:],
-                                       targets[:, n_chunks * chunk:])
-        return total / (b * sm1) + aux
+                total, _ = jax.lax.scan(
+                    body, total,
+                    (jnp.moveaxis(h_chunks, 1, 0), jnp.moveaxis(t_chunks, 1, 0)))
+            if rem:
+                total = total + chunk_loss(h[:, n_chunks * chunk:],
+                                           targets[:, n_chunks * chunk:])
+            return total / (b * sm1) + aux
 
     # -- serving ----------------------------------------------------------------
     def init_cache(self, batch: int, capacity: int, dtype=None) -> dict:
@@ -1021,7 +1059,8 @@ class Transformer:
         h, _, caches = self._backbone(params, x, positions, enc,
                                       collect_cache=True,
                                       use_flash=self.cfg.flash_prefill)
-        logits = self._head(params, h[:, -1:])
+        with phase(PHASE_MODEL_HEAD):
+            logits = self._head(params, h[:, -1:])
         return logits[:, 0], caches
 
     def decode_step(self, params, cache, token, pos, enc=None):
@@ -1038,6 +1077,7 @@ class Transformer:
         for i, g in enumerate(self.groups):
             x, c = g.decode(params[f"group_{i}"], x, pos, cache[f"group_{i}"], enc=enc)
             new_cache[f"group_{i}"] = c
-        x = rms_norm(params["final_ln"], x, cfg.norm_eps)
-        logits = self._head(params, x)
+        with phase(PHASE_MODEL_HEAD):
+            x = rms_norm(params["final_ln"], x, cfg.norm_eps)
+            logits = self._head(params, x)
         return logits[:, 0], new_cache

@@ -3,10 +3,12 @@
 Six pieces, all wired through the session's RoundHook seam:
 
 * **Phase tracing** (:mod:`repro.obs.trace`): ``jax.named_scope``
-  annotations on the round phases (metadata-only — the golden-HLO pins
-  stay binding) plus the profiling join that turns a ``jax.profiler``
-  trace into a per-phase device-time breakdown
-  (:meth:`repro.api.Session.profile`).
+  annotations on the round phases and the model blocks (metadata-only),
+  ``span()`` host spans that the api and engine layers open around their
+  host work (one ``HOST_SPANS`` vocabulary, on the profiler's clock), the
+  process-wide compile counter behind ``RunReport.counts``, and the
+  profiling join that turns a ``jax.profiler`` trace into a per-phase
+  device-time breakdown (:meth:`repro.api.Session.profile`).
 * **Metrics/event bus** (:mod:`repro.obs.metrics`): one timestamped
   :class:`Event` schema, counter/gauge/histogram aggregates, and the
   ``repro.obs`` logger that the hooks' warn/print sinks route through.
@@ -42,11 +44,12 @@ from repro.obs.metrics import (
     get_logger,
     log_sink,
 )
-from repro.obs.trace import KNOWN_PHASES, ProfileReport, phase
+from repro.obs.trace import HOST_SPANS, KNOWN_PHASES, ProfileReport, phase, span
 
 __all__ = [
     "Alert",
     "Event",
+    "HOST_SPANS",
     "JsonlExporter",
     "KNOWN_PHASES",
     "MetricGate",
@@ -62,6 +65,7 @@ __all__ = [
     "log_sink",
     "phase",
     "prometheus_text",
+    "span",
     "validate_chrome_trace",
     "write_prometheus",
 ]

@@ -165,8 +165,8 @@ class Timeline:
         last recorded event): trace -> compile -> execute on the host
         profile track, and the xplane-joined per-phase device seconds as
         sequential slices on the device track under the execute window.
-        An empty phase dict (no xplane protobuf) leaves the device track
-        empty; the profile's ``note`` is kept in ``meta``.
+        An empty phase dict (no op executions traced) leaves the device
+        track empty; the profile's ``note`` is kept in ``meta``.
         """
         base = at if at is not None else self.end_ts()
         t = base

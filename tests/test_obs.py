@@ -65,7 +65,11 @@ def _session(**kw):
 
 
 def _strip_hlo_noise(txt: str) -> str:
+    """Compiled HLO without its debug information: the per-op metadata
+    and the module's source-location tables (FileNames ... StackFrames)."""
     txt = re.sub(r"metadata=\{[^}]*\}", "", txt)
+    txt = re.sub(r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)"
+                 r"\n(?:.+\n)*", "", txt, flags=re.M)
     return re.sub(r'"[^"]*source_file[^"]*"', "", txt)
 
 
@@ -95,6 +99,47 @@ def test_phase_scope_is_metadata_only():
     scoped = jax.jit(_mk(True)).lower(1.0).compile().as_text()
     assert _strip_hlo_noise(bare) == _strip_hlo_noise(scoped)
     assert "unit_test_scope" in KNOWN_PHASES
+
+
+def _xlstm_grad_hlo(monkeypatch=None):
+    """Compiled HLO of a tiny xLSTM Transformer's loss gradient; with
+    ``monkeypatch`` the model's phase scopes are replaced by no-ops."""
+    import contextlib
+
+    from repro.models import Transformer
+    from repro.models import transformer as tf
+    from repro.models.config import ModelConfig, XLSTMGroup
+
+    if monkeypatch is not None:
+        monkeypatch.setattr(tf, "phase",
+                            lambda name: contextlib.nullcontext())
+    model = Transformer(ModelConfig(
+        name="tiny-xlstm", d_model=16, vocab_size=32, n_heads=2,
+        n_kv_heads=2, head_dim=16, d_ff=0, tie_embedding=True,
+        groups=(XLSTMGroup(n_units=1, mlstm_per_unit=2, proj_factor=2.0),)))
+    params = model.init(jax.random.PRNGKey(0))
+    batch = {"tokens": jnp.zeros((2, 6), jnp.int32)}
+    return jax.jit(jax.grad(model.loss_fn)).lower(
+        params, batch).compile().as_text()
+
+
+def test_model_block_scopes_name_the_gradient_and_change_no_op(monkeypatch):
+    """The model's block scopes reach the compiled gradient's op_name —
+    forward and backward — and are metadata only: the program is the
+    unscoped one once debug information is stripped."""
+    def components(hlo):
+        return {part for name in re.findall(r'op_name="([^"]*)"', hlo)
+                for part in name.split("/")}
+
+    scoped = _xlstm_grad_hlo()
+    named = components(scoped)
+    for name in ("model_embed", "model_mlstm", "model_slstm", "model_head"):
+        assert name in KNOWN_PHASES
+        assert any(name in part for part in named), name
+    assert any(p.startswith("transpose(jvp(") for p in named)
+    bare = _xlstm_grad_hlo(monkeypatch)
+    assert not any("model_" in part for part in components(bare))
+    assert _strip_hlo_noise(bare) == _strip_hlo_noise(scoped)
 
 
 def test_round_phases_annotate_compiled_hlo():

@@ -453,9 +453,9 @@ class MetricsHook(RoundHook):
     def finish_run(self, report: Any) -> None:
         """Publish the report's wall-clock split as ``run.compile_s`` /
         ``run.run_s`` gauges and its counts as ``run.dispatches`` /
-        ``run.host_syncs`` / ``run.compiles`` — exporters and the
-        cross-run registry read them off the bus instead of parsing
-        RunReports."""
+        ``run.host_syncs`` / ``run.readback_leaves`` / ``run.compiles`` —
+        exporters and the cross-run registry read them off the bus instead
+        of parsing RunReports."""
         bus = self.bus = _resolve_bus(self.bus)
         bus.gauge("run.compile_s", float(report.compile_s))
         bus.gauge("run.run_s", float(report.run_s))

@@ -98,10 +98,12 @@ class RunReport:
                      ``dispatches`` (compiled segments enqueued),
                      ``host_syncs`` (times the host waited on the device:
                      the first-segment sync of a compiling call, every
-                     per-segment sync a span hook asks for, each
-                     trajectory leaf read back) and ``compiles`` (the
-                     change over the call in the process-wide count of
-                     lowerings + backend compiles). The call's
+                     per-segment sync a span hook asks for, and the one
+                     batched sync that reads the whole trajectory back),
+                     ``readback_leaves`` (the trajectory leaves of every
+                     segment that batched sync fetched) and ``compiles``
+                     (the change over the call in the process-wide count
+                     of lowerings + backend compiles). The call's
                      ``repro.api.report`` host span carries them as stats.
     """
 

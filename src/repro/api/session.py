@@ -140,6 +140,15 @@ def _own_buffers(state: Any) -> Any:
         lambda x: x.copy() if hasattr(x, "copy") else x, state)
 
 
+def _copy_to_host_async(tree: Any) -> None:
+    """Start the device-to-host copy of every device leaf of ``tree``
+    without waiting: the copies queue behind the work that makes them,
+    and a later read finds them done or in flight."""
+    for x in jax.tree_util.tree_leaves(tree):
+        if isinstance(x, jax.Array):
+            x.copy_to_host_async()
+
+
 def _broadcast_nodes(params: PyTree, n_nodes: int) -> PyTree:
     """Single-node params -> node-stacked (every node starts identical).
 
@@ -497,7 +506,11 @@ class ProtocolSession:
         device time.
 
         Every wait on the device is a ``repro.api.wait`` span and counts
-        in ``RunReport.counts["host_syncs"]``.
+        in ``RunReport.counts["host_syncs"]``. Each segment's trajectory
+        leaves start their copy to the host as the segment is received;
+        the report reads them all back in one batched sync
+        (``jax.device_get``), and ``RunReport.counts["readback_leaves"]``
+        counts the leaves that sync fetched.
         """
         t_start = time.time()
         compile_s = 0.0
@@ -534,6 +547,7 @@ class ProtocolSession:
                     if first and compiled:
                         compile_s = exec_end - t_start
                 trajs.append(traj)
+                _copy_to_host_async(traj)
                 if hooks:
                     with span(SPAN_API_HOOKS, call=call):
                         for h in hooks:
@@ -556,11 +570,11 @@ class ProtocolSession:
                         h.finish()
         with span(SPAN_API_REPORT, call=call) as report_span:
             trajectory = {}
+            leaves = sum(len(t) for t in trajs)
             if trajs:
-                keys = trajs[0].keys()
-                trajectory = {k: np.concatenate([wait(np.asarray, t[k])
-                                                 for t in trajs])
-                              for k in keys}
+                host = wait(jax.device_get, trajs)
+                trajectory = {k: np.concatenate([t[k] for t in host])
+                              for k in trajs[0]}
             executed = done - start
             # Any hook exposing network_stats() (repro.net.stats.
             # NetworkStatsHook — duck-typed so repro.api never imports
@@ -571,6 +585,7 @@ class ProtocolSession:
                 if stats_fn is not None:
                     network = stats_fn()
             counts = {"dispatches": len(trajs), "host_syncs": syncs,
+                      "readback_leaves": leaves,
                       "compiles": compile_count() - compiles0}
             report_span.set_metadata(**counts)
             report = RunReport(

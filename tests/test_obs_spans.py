@@ -1,9 +1,10 @@
 """Host spans and counts inside Session and the engine: every call opens
 the span tree of repro.obs.trace under one ``call`` id, RunReport.counts
-counts its dispatches, host syncs and compiles, a call that compiled
-nothing makes no instrumentation sync, and Session.profile's join reads
-the trace through jax.profiler.ProfileData (also a recorded TPU v5e
-trace)."""
+counts its dispatches, host syncs, trajectory leaves read back and
+compiles, a call that compiled nothing makes one host sync (the batched
+trajectory readback, which equals reading each segment's leaves back),
+and Session.profile's join reads the trace through
+jax.profiler.ProfileData (also a recorded TPU v5e trace)."""
 import glob
 import gzip
 import math
@@ -12,10 +13,11 @@ import shutil
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
-from repro.api import MetricsHook, PrivacySpec, Session
+from repro.api import BudgetHook, MetricsHook, PrivacySpec, Session
 from repro.core.topology import DOutGraph, calibrate_constants
 from repro.obs import MetricsBus
 from repro.obs.trace import (
@@ -125,9 +127,12 @@ def test_run_opens_the_span_tree_under_one_call_id(tmp_path):
         assert {k: rep[3][k] for k in report.counts} == report.counts
         assert report.counts["dispatches"] == segments
     # The first call compiled and synced its first segment; the second
-    # compiled nothing, so it only reads the trajectory back.
-    assert first.counts["host_syncs"] == 1 + segments * leaves
-    assert second.counts["host_syncs"] == segments * leaves
+    # compiled nothing, so it only reads the trajectory back, every
+    # segment's leaves in one batched sync.
+    assert first.counts["host_syncs"] == 1 + 1
+    assert second.counts["host_syncs"] == 1
+    for report in (first, second):
+        assert report.counts["readback_leaves"] == segments * leaves
     (consensus,) = _named(spans, SPAN_API_CONSENSUS)
     assert consensus[3]["call"] == 2 and consensus[1] >= runs[1][2]
     assert set(HOST_SPANS) >= {s[0] for s in spans}
@@ -174,8 +179,9 @@ def test_run_with_eps_at_stacks_inputs_in_a_span(tmp_path):
 def test_dispatches_count_the_segments(rounds, chunk):
     report = _session(chunk=chunk).run(rounds, values=_values())
     assert report.counts["dispatches"] == math.ceil(rounds / chunk)
-    assert report.counts["host_syncs"] == (
-        1 + report.counts["dispatches"] * len(report.trajectory))
+    assert report.counts["host_syncs"] == 1 + 1
+    assert report.counts["readback_leaves"] == (
+        report.counts["dispatches"] * len(report.trajectory))
 
 
 def test_loop_driver_counts_one_dispatch_a_round():
@@ -191,7 +197,8 @@ def test_a_call_that_compiles_nothing_makes_no_instrumentation_sync():
     assert first.counts["compiles"] > 0 and first.compile_s > 0.0
     assert second.counts["compiles"] == 0
     assert second.compile_s == 0.0 and second.run_s > 0.0
-    assert second.counts["host_syncs"] == first.counts["host_syncs"] - 1
+    assert first.counts["host_syncs"] == 1 + 1
+    assert second.counts["host_syncs"] == 1
     assert second.summary()["counts"] == second.counts
 
 
@@ -204,12 +211,60 @@ def test_span_hooks_sync_every_segment_and_see_no_compile_twice():
     second = session.run(T, values=_values(), hooks=[hook])
     segments = math.ceil(T / 3)
     assert first.compile_s > 0.0 and second.compile_s == 0.0
-    assert second.counts["host_syncs"] == segments * (
-        1 + len(second.trajectory))
+    assert second.counts["host_syncs"] == segments + 1
+    assert second.counts["readback_leaves"] == (
+        segments * len(second.trajectory))
     spans = [e["name"] for e in hook.timeline.to_chrome_trace()[
         "traceEvents"] if e.get("cat") == "segment" and e["tid"] == 1]
     assert spans == ["trace/compile+execute"] + ["execute"] * (
         2 * segments - 1)
+
+
+def _train(rounds, **kw):
+    session, batch_at = _train_session()
+    return session.train(rounds, batch_at, **kw)
+
+
+def _strict_budget_run():
+    session = _session()
+    hook = BudgetHook(1.5 * session.cfg.epsilon_per_round, strict=True,
+                      warn=lambda s: None)
+    report = session.run(T, values=_values(), hooks=[hook])
+    assert report.aborted and 0 < report.rounds < T
+    return report
+
+
+@pytest.mark.parametrize("call", [
+    lambda: _session().run(T, values=_values()),
+    lambda: _session().run(T, values=_values(), eps_at=lambda t: [
+        jnp.full_like(x, 0.01 * (t + 1)) for x in _values()]),
+    lambda: _train(T),
+    lambda: _train(4, driver="loop"),
+    _strict_budget_run,
+], ids=["run", "run_eps_at", "train_engine", "train_loop",
+        "run_budget_abort"])
+def test_batched_readback_equals_reading_each_segment(call, monkeypatch):
+    """The report's trajectory, read back in one batched sync, is what
+    reading every leaf of every segment back one by one gives: values,
+    dtypes, shapes and key order."""
+    from repro.api import session as session_mod
+
+    trajs = []
+    start = session_mod._copy_to_host_async
+
+    def record(traj):
+        trajs.append(traj)
+        start(traj)
+
+    monkeypatch.setattr(session_mod, "_copy_to_host_async", record)
+    report = call()
+    assert len(trajs) == report.counts["dispatches"] > 0
+    assert list(report.trajectory) == list(trajs[0])
+    assert report.counts["readback_leaves"] == len(trajs) * len(trajs[0])
+    for k, got in report.trajectory.items():
+        want = np.concatenate([np.asarray(t[k]) for t in trajs])
+        assert got.dtype == want.dtype and got.shape == want.shape, k
+        assert np.array_equal(got, want), k
 
 
 def test_compile_count_grows_with_each_new_program():

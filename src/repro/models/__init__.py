@@ -4,7 +4,6 @@ mLSTM/sLSTM, Mamba2 hybrid, cross-attention VLM, audio-token decoders)."""
 from repro.models.config import (
     AttnGroup,
     CrossSelfGroup,
-    MambaGroup,
     ModelConfig,
     MoEGroup,
     XLSTMGroup,
@@ -17,7 +16,6 @@ __all__ = [
     "AttnGroup",
     "MoEGroup",
     "XLSTMGroup",
-    "MambaGroup",
     "ZambaGroup",
     "CrossSelfGroup",
     "Transformer",

@@ -13,10 +13,9 @@ Heterogeneous layer patterns are expressed as structured groups:
 * ``MoEGroup``      — GQA attention + top-1 routed experts (GShard-style
                       scatter dispatch, optional shared expert).
 * ``XLSTMGroup``    — repeating [m x mLSTM, 1 x sLSTM] units (xLSTM).
-* ``MambaGroup``    — n Mamba2 (SSD) blocks.
-* ``ZambaGroup``    — repeating [m x Mamba2, 1 x shared-weight attention]
-                      units; the attention block's weights are shared across
-                      all units (Zamba2's signature trick).
+* ``ZambaGroup``    — Zamba2's hybrid stack: a Mamba2 mixer in every
+                      layer, and in each "hybrid" layer one of a few
+                      weight-tied transformer blocks in front of it.
 * ``CrossSelfGroup``— repeating [1 x cross-attention, m x self-attention]
                       units consuming stub image embeddings (Llama-3.2-V).
 """
@@ -29,7 +28,6 @@ __all__ = [
     "AttnGroup",
     "MoEGroup",
     "XLSTMGroup",
-    "MambaGroup",
     "ZambaGroup",
     "CrossSelfGroup",
     "ModelConfig",
@@ -116,31 +114,45 @@ class XLSTMGroup:
 
 
 @dataclasses.dataclass(frozen=True)
-class MambaGroup:
-    n_layers: int
-    d_state: int = 64
-    expand: int = 2
-
-    kind: str = dataclasses.field(default="mamba", init=False)
-
-    @property
-    def total_layers(self) -> int:
-        return self.n_layers
-
-
-@dataclasses.dataclass(frozen=True)
 class ZambaGroup:
-    n_units: int                   # each unit = mamba_per_unit Mamba2 + shared attn
-    mamba_per_unit: int = 6
-    trailing_mamba: int = 0
+    """Zamba2 (arXiv:2411.15242; ``transformers`` ``Zamba2Config``).
+
+    Every layer holds a Mamba2 mixer of ``expand * d_model /
+    mamba_headdim`` heads in ``mamba_ngroups`` B/C groups. A "hybrid"
+    layer first applies transformer block ``k mod num_mem_blocks`` (its
+    k-th application; the blocks' weights are tied across applications)
+    to concat(h, token embedding), with a rank-``adapter_rank`` adapter on
+    the MLP's gate_up and a d x d ``linear`` of its own, and adds the result
+    to its Mamba2 layer's input. Attention widths come from the model
+    (``n_heads`` of ``head_dim`` over 2 * d_model in), the MLP's from
+    ``d_ff``.
+    """
+
+    layers_block_type: Tuple[str, ...]   # "mamba" | "hybrid", one per layer
+    num_mem_blocks: int = 2
+    adapter_rank: int = 128
+    mamba_headdim: int = 64
+    mamba_ngroups: int = 2
     d_state: int = 64
+    d_conv: int = 4
     expand: int = 2
+    chunk_size: int = 256
+    time_step_min: float = 1e-3
 
     kind: str = dataclasses.field(default="zamba", init=False)
 
+    def __post_init__(self):
+        if set(self.layers_block_type) - {"mamba", "hybrid"}:
+            raise ValueError("layers_block_type holds 'mamba' or 'hybrid'")
+
+    @property
+    def hybrid_layer_ids(self) -> Tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.layers_block_type)
+                     if t == "hybrid")
+
     @property
     def total_layers(self) -> int:
-        return self.n_units * (self.mamba_per_unit + 1) + self.trailing_mamba
+        return len(self.layers_block_type)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -179,7 +191,7 @@ class ModelConfig:
     param_dtype: str = "float32"
     # Eligible for the long_500k decode shape (SSM/hybrid state, or a mostly
     # sliding-window dense stack). Pure full-attention archs keep False and
-    # skip long_500k per DESIGN.md.
+    # skip long_500k.
     long_context_ok: bool = False
     # SPerf optimization: keep the layer-stacked KV cache in the decode
     # scan *carry* and update it in place (one token-slot write + one
@@ -190,6 +202,11 @@ class ModelConfig:
     # flash-attention kernel (O(S*D) HBM traffic instead of materialized
     # (S, S) scores). Forward-only — applies to prefill, not training.
     flash_prefill: bool = False
+    # The tensor-parallel share this model holds, (rank, size): each layer
+    # keeps 1/size of its heads, B/C groups and MLP columns, and the
+    # vocabulary keeps rows [rank, rank + 1) * vocab_size / size. Size 1 is
+    # the whole model. Only the Zamba group divides its layers.
+    share: Tuple[int, int] = (0, 1)
     # citation for the assigned-architecture table
     source: str = ""
 
@@ -200,6 +217,12 @@ class ModelConfig:
             raise ValueError(f"unknown input_mode {self.input_mode!r}")
         if self.n_heads % max(self.n_kv_heads, 1) != 0:
             raise ValueError("n_heads must be divisible by n_kv_heads")
+        rank, size = self.share
+        if not 0 <= rank < size or self.vocab_size % size:
+            raise ValueError(f"bad share {self.share} of vocab "
+                             f"{self.vocab_size}")
+        if size > 1 and any(g.kind != "zamba" for g in self.groups):
+            raise ValueError("only Zamba layers divide into shares")
 
     @property
     def total_layers(self) -> int:

@@ -5,16 +5,31 @@ All mixers expose:
   *_seq(params, x)                           -> (y, final_state)   # training
   *_step(params, x_t, state)                 -> (y_t, new_state)   # decode
 
-Training uses ``lax.scan`` over time (the faithful recurrent form — the
-chunkwise-parallel reformulations are a possible future kernel; see
-DESIGN.md). Decode is O(1) state per token, which is what makes the ssm /
-hybrid architectures long_500k-eligible.
+The xLSTM cells train with ``lax.scan`` over time (the recurrent form);
+Mamba2 trains with the chunked SSD scan (``ssd_chunked``) and decodes with
+its recurrence. Decode is O(1) state per token, which is what makes the
+ssm / hybrid architectures long_500k-eligible.
 
-Simplifications vs. the reference implementations (documented deviations):
-the short causal conv in Mamba2 and the mLSTM block's depthwise conv are
+xLSTM departures from the paper: the mLSTM block's depthwise conv is
 omitted; gate biases init to small constants for stable exp-gating.
+
+Mamba2 follows ``transformers``' ``Zamba2MambaMixer.torch_forward`` (the
+slow path): in_proj to z, x, B, C, dt (no gated-MLP part, d_mlp = 0); a
+causal depthwise conv (with bias) and SiLU over [x, B, C]; B/C in groups
+shared by their heads; dt = softplus(dt + dt_bias) floored at
+time_step_min as that path floors it (no time_step_limit); A = -exp(A_log);
+the D skip; RMSNormGated(y, z) over each group's channels, eps 1e-5; out
+projection. Departures: the SSD chunk length is min(chunk_size, L) (the
+same sums; the slow path pads to whole 256-token chunks); the state
+between chunks is one decay-matrix product where the slow path builds the
+matrix from a segment sum, and it sums over the source chunk: the slow
+path of transformers 4.57.6 sums over the target chunk, which is wrong
+beyond one chunk, so this follows the recurrence that its cached decode
+runs (tests/bench/test_bench_zamba2_refs.py).
 """
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +39,8 @@ from repro.models.layers import dense_init
 __all__ = [
     "init_mlstm", "mlstm_seq", "mlstm_step", "mlstm_state",
     "init_slstm", "slstm_seq", "slstm_step", "slstm_state",
-    "init_mamba2", "mamba2_seq", "mamba2_step", "mamba2_state",
+    "Mamba2Dims", "init_mamba2", "mamba2_seq", "mamba2_step", "mamba2_state",
+    "ssd_chunked",
 ]
 
 
@@ -189,85 +205,186 @@ def slstm_step(params: dict, x: jnp.ndarray, state: dict):
 
 
 # ---------------------------------------------------------------------------
-# Mamba2 (state-space duality layer, recurrent form; arXiv:2405.21060)
+# Mamba2 (state-space duality layer, arXiv:2405.21060), as Zamba2 runs it
 # ---------------------------------------------------------------------------
 
-def init_mamba2(key: jax.Array, d_model: int, d_state: int = 64, expand: int = 2,
-                head_dim: int = 64, dtype=jnp.float32) -> dict:
-    d_inner = expand * d_model
-    assert d_inner % head_dim == 0
-    nh = d_inner // head_dim
-    ki, kb, kc, kdt, ko = jax.random.split(key, 5)
+class Mamba2Dims(NamedTuple):
+    """What one Mamba2 mixer holds: ``n_heads`` heads of ``head_dim`` fed by
+    ``n_groups`` B/C groups of ``d_state``, a causal depthwise conv of
+    ``d_conv`` taps, SSD chunks of ``chunk_size``; dt floored at ``dt_min``.
+    A tensor-parallel share holds whole groups, with their heads."""
+
+    n_heads: int
+    head_dim: int
+    n_groups: int
+    d_state: int
+    d_conv: int = 4
+    chunk_size: int = 256
+    dt_min: float = 1e-3
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.n_groups * self.d_state
+
+
+def init_mamba2(key: jax.Array, d_model: int, dims: Mamba2Dims,
+                dtype=jnp.float32) -> dict:
+    """``in_proj`` columns are [z | x | B | C | dt]; dt_bias is the inverse
+    softplus of a log-uniform dt in [1e-3, 0.1] and A_log = log(1..H), as
+    the published initializer sets them."""
+    ki, kc, ko, kt = jax.random.split(key, 4)
+    di, nh = dims.d_inner, dims.n_heads
+    dt = jnp.exp(jax.random.uniform(kt, (nh,), jnp.float32)
+                 * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
+    dt = jnp.maximum(dt, 1e-4)
     return {
-        "w_in": dense_init(ki, (d_model, 2 * d_inner), dtype),   # x and gate z
-        "w_b": dense_init(kb, (d_model, d_state), dtype),
-        "w_c": dense_init(kc, (d_model, d_state), dtype),
-        "w_dt": dense_init(kdt, (d_model, nh), dtype),
-        "b_dt": jnp.full((nh,), -2.0, dtype),     # softplus(-2) ~ 0.13
-        "a_log": jnp.zeros((nh,), dtype),         # A = -exp(a_log) = -1
+        "in_proj": dense_init(ki, (d_model, di + dims.conv_dim + nh), dtype),
+        "conv_w": dense_init(kc, (dims.d_conv, dims.conv_dim), dtype),
+        "conv_b": jnp.zeros((dims.conv_dim,), dtype),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+        "a_log": jnp.log(jnp.arange(1, nh + 1, dtype=jnp.float32)).astype(dtype),
         "d_skip": jnp.ones((nh,), dtype),
-        "w_out": dense_init(ko, (d_inner, d_model), dtype),
+        "norm": jnp.ones((di,), dtype),
+        "out_proj": dense_init(ko, (di, d_model), dtype),
     }
 
 
-def mamba2_state(batch: int, d_model: int, d_state: int = 64, expand: int = 2,
-                 head_dim: int = 64, dtype=jnp.float32) -> dict:
-    nh = expand * d_model // head_dim
-    return {"h": jnp.zeros((batch, nh, d_state, head_dim), dtype)}
+def mamba2_state(batch: int, dims: Mamba2Dims, dtype=jnp.float32) -> dict:
+    """``ssm`` (B, H, P, N) and the conv's last ``d_conv - 1`` inputs."""
+    return {"ssm": jnp.zeros((batch, dims.n_heads, dims.head_dim,
+                              dims.d_state), dtype),
+            "conv": jnp.zeros((batch, dims.d_conv - 1, dims.conv_dim), dtype)}
 
 
-def _mamba2_proj(params, x, head_dim: int):
-    hd = head_dim
-    nh = params["w_dt"].shape[1]
-    xz = x @ params["w_in"]
-    d_inner = nh * hd
-    xi, z = xz[..., :d_inner], xz[..., d_inner:]
-    xh = xi.reshape(xi.shape[:-1] + (nh, hd))                     # (B,S,nh,hd)
-    bmat = x @ params["w_b"]                                      # (B,S,n)
-    cmat = x @ params["w_c"]                                      # (B,S,n)
-    dt = jax.nn.softplus((x @ params["w_dt"] + params["b_dt"]).astype(jnp.float32))
-    return xh, z, bmat, cmat, dt
+def _ssd_chunk(xdt, acum, b, c):
+    """One chunk: its own part of y, (C B^T o L) (dt x), and the state it
+    adds by its end. xdt (B, T, G, H/G, P), acum (B, T, H) the running sum
+    of dt A inside the chunk, b/c (B, T, G, N)."""
+    bsz, t, ng, hg, _ = xdt.shape
+    ah = jnp.moveaxis(acum, 1, 2)                          # (B, H, T)
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    seg = jnp.exp(jnp.where(causal, ah[..., :, None] - ah[..., None, :],
+                            -jnp.inf)).reshape(bsz, ng, hg, t, t)
+    cb = jnp.einsum("bign,bjgn->bgij", c, b)
+    y = jnp.einsum("bgeij,bjgep->bigep", seg * cb[:, :, None], xdt)
+    to_end = jnp.exp(acum[:, -1:] - acum).reshape(bsz, t, ng, hg)
+    return y, jnp.einsum("bjgn,bjge,bjgep->bgepn", b, to_end, xdt)
 
 
-def _mamba2_cell(a_neg, d_skip, carry, inp):
-    h = carry                                      # (B,nh,n,hd) f32
-    xh, bmat, cmat, dt = inp                       # (B,nh,hd), (B,n), (B,n), (B,nh)
-    decay = jnp.exp(dt * a_neg[None, :])           # (B,nh)
-    xb = (dt[..., None, None] * bmat[:, None, :, None].astype(jnp.float32)
-          * xh[:, :, None, :].astype(jnp.float32))                    # (B,nh,n,hd)
-    h_new = decay[..., None, None] * h + xb
-    y = jnp.einsum("bn,bhnd->bhd", cmat.astype(jnp.float32), h_new)
-    y = y + d_skip[None, :, None] * xh.astype(jnp.float32)
-    return h_new, y
+def ssd_chunked(x, dt, a, b, c, chunk_size: int, h0=None):
+    """The SSD scan in chunks: y_t = sum_{s<=t} C_t.B_s exp(sum_{s<u<=t} dt_u A)
+    dt_s x_s, and the final state.
+
+    x (B, L, H, P), dt (B, L, H), a (H,) negative, b/c (B, L, G, N) with head
+    h reading group h // (H / G); h0 (B, H, P, N) or None. Inside a chunk
+    the (C B^T o L) X matmul (``_ssd_chunk``, one chunk at a time and
+    recomputed for the backward pass, so only one chunk's (T, T) decays
+    are held); across chunks a recurrence on the chunk states, as one
+    matmul over the (chunks + 1)^2 decay matrix. The sequence is
+    zero-padded to whole chunks (dt 0 neither decays nor adds).
+    """
+    bsz, seq, nh, hp = x.shape
+    ng, n = b.shape[2], b.shape[3]
+    hg = nh // ng
+    t = min(chunk_size, seq)
+    pad = (-seq) % t
+    nc = (seq + pad) // t
+    f32 = jnp.float32
+
+    def chunks(v):
+        v = jnp.pad(v.astype(f32), [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2))
+        return v.reshape((bsz, nc, t) + v.shape[2:])
+
+    xdt = chunks(x * dt[..., None]).reshape(bsz, nc, t, ng, hg, hp)
+    acum = jnp.cumsum(chunks(dt * a), axis=2)             # (B, c, T, H)
+    b, c = chunks(b), chunks(c)                           # (B, c, T, G, N)
+    y_diag, states = jax.lax.map(
+        lambda v: jax.checkpoint(_ssd_chunk)(*v),
+        tuple(jnp.moveaxis(v, 1, 0) for v in (xdt, acum, b, c)))
+    y_diag = jnp.moveaxis(y_diag, 0, 1)
+    states = jnp.moveaxis(states, 0, 1).reshape(bsz, nc, nh, hp, n)
+    if h0 is None:
+        h0 = jnp.zeros((bsz, nh, hp, n), f32)
+    src = jnp.concatenate([h0.astype(f32)[:, None], states], axis=1)
+    cs = jnp.cumsum(jnp.pad(acum[:, :, -1], [(0, 0), (1, 0), (0, 0)]), axis=1)
+    keep = jnp.tril(jnp.ones((nc + 1, nc + 1), bool))[..., None]
+    decay = jnp.exp(jnp.where(keep, cs[:, :, None] - cs[:, None], -jnp.inf))
+    enter = jnp.einsum("bmkh,bkhpn->bmhpn", decay, src)  # state entering m
+    prev = enter[:, :nc].reshape(bsz, nc, ng, hg, hp, n)
+    y_off = (jnp.einsum("bcign,bcgepn->bcigep", c, prev)
+             * jnp.exp(acum).reshape(bsz, nc, t, ng, hg)[..., None])
+    y = (y_diag + y_off).reshape(bsz, nc * t, nh, hp)[:, :seq]
+    return y, enter[:, nc]
 
 
-def mamba2_seq(params: dict, x: jnp.ndarray, *, head_dim: int = 64, state: dict | None = None):
-    b, s, d = x.shape
+def _mamba2_in(params, x, dims: Mamba2Dims):
+    """in_proj -> (z, pre-conv [x | B | C], dt before its bias)."""
+    zxbcdt = x @ params["in_proj"]
+    di, cd = dims.d_inner, dims.conv_dim
+    return zxbcdt[..., :di], zxbcdt[..., di:di + cd], zxbcdt[..., di + cd:]
+
+
+def _mamba2_ssm_in(params, xbc, dt, dims: Mamba2Dims):
+    """Post-conv [x | B | C] and raw dt -> per-head x, grouped B and C,
+    dt = max(softplus(dt + dt_bias), dt_min), A = -exp(A_log)."""
+    lead = xbc.shape[:-1]
+    di, gn = dims.d_inner, dims.n_groups * dims.d_state
+    xs = xbc[..., :di].reshape(lead + (dims.n_heads, dims.head_dim))
+    bm = xbc[..., di:di + gn].reshape(lead + (dims.n_groups, dims.d_state))
+    cm = xbc[..., di + gn:].reshape(lead + (dims.n_groups, dims.d_state))
+    dt = jnp.maximum(jax.nn.softplus(
+        dt.astype(jnp.float32) + params["dt_bias"].astype(jnp.float32)),
+        dims.dt_min)
+    a = -jnp.exp(params["a_log"].astype(jnp.float32))
+    return xs.astype(jnp.float32), bm, cm, dt, a
+
+
+def _mamba2_out(params, y, z, dims: Mamba2Dims, out_dtype):
+    """y * silu(z), RMS-normed over each B/C group's channels (eps 1e-5),
+    scaled, then out_proj."""
+    y = y * jax.nn.silu(z.astype(jnp.float32))
+    yg = y.reshape(y.shape[:-1] + (dims.n_groups, -1))
+    yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True) + 1e-5)
+    y = yg.reshape(y.shape) * params["norm"].astype(jnp.float32)
+    return y.astype(out_dtype) @ params["out_proj"]
+
+
+def mamba2_seq(params: dict, x: jnp.ndarray, dims: Mamba2Dims,
+               state: dict | None = None):
+    """x (B, L, d_model) -> (out, state after the last token). Training and
+    prefill: the conv over the sequence, then the chunked SSD."""
+    bsz, seq, _ = x.shape
     if state is None:
-        d_state = params["w_b"].shape[1]
-        state = mamba2_state(b, d, d_state, params["w_in"].shape[1] // (2 * d), head_dim)
-    xh, z, bmat, cmat, dt = _mamba2_proj(params, x, head_dim)
-    a_neg = -jnp.exp(params["a_log"].astype(jnp.float32))
-    d_skip = params["d_skip"].astype(jnp.float32)
-    inputs = tuple(jnp.moveaxis(t, 1, 0) for t in (xh, bmat, cmat, dt))
-    carry, ys = jax.lax.scan(
-        lambda c, t: _mamba2_cell(a_neg, d_skip, c, t),
-        state["h"].astype(jnp.float32), inputs,
-    )
-    y = jnp.moveaxis(ys, 0, 1).reshape(b, s, -1).astype(x.dtype)
-    y = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
-    return y @ params["w_out"], {"h": carry}
+        state = mamba2_state(bsz, dims)
+    z, xbc, dt = _mamba2_in(params, x, dims)
+    xpad = jnp.concatenate([state["conv"].astype(xbc.dtype), xbc], axis=1)
+    conv = params["conv_b"] + sum(
+        xpad[:, k:k + seq] * params["conv_w"][k] for k in range(dims.d_conv))
+    xs, bm, cm, dt, a = _mamba2_ssm_in(params, jax.nn.silu(conv), dt, dims)
+    y, h = ssd_chunked(xs, dt, a, bm, cm, dims.chunk_size, state["ssm"])
+    y = y + params["d_skip"].astype(jnp.float32)[:, None] * xs
+    out = _mamba2_out(params, y.reshape(bsz, seq, -1), z, dims, x.dtype)
+    return out, {"ssm": h, "conv": xpad[:, seq:]}
 
 
-def mamba2_step(params: dict, x: jnp.ndarray, state: dict, *, head_dim: int = 64):
-    xh, z, bmat, cmat, dt = _mamba2_proj(params, x, head_dim)
-    a_neg = -jnp.exp(params["a_log"].astype(jnp.float32))
-    d_skip = params["d_skip"].astype(jnp.float32)
-    carry, y = _mamba2_cell(
-        a_neg, d_skip, state["h"].astype(jnp.float32),
-        (xh[:, 0], bmat[:, 0], cmat[:, 0], dt[:, 0]),
-    )
-    b = x.shape[0]
-    y = y.reshape(b, 1, -1).astype(x.dtype)
-    y = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
-    return y @ params["w_out"], {"h": carry}
+def mamba2_step(params: dict, x: jnp.ndarray, dims: Mamba2Dims, state: dict):
+    """x (B, 1, d_model): one token through the conv window and the
+    recurrent state (decode)."""
+    z, xbc, dt = _mamba2_in(params, x, dims)
+    window = jnp.concatenate([state["conv"].astype(xbc.dtype), xbc], axis=1)
+    conv = params["conv_b"] + jnp.sum(window * params["conv_w"], axis=1)
+    xs, bm, cm, dt, a = _mamba2_ssm_in(params, jax.nn.silu(conv), dt[:, 0],
+                                       dims)
+    rep = dims.n_heads // dims.n_groups
+    bh = jnp.repeat(bm.astype(jnp.float32), rep, axis=1)     # (B, H, N)
+    ch = jnp.repeat(cm.astype(jnp.float32), rep, axis=1)
+    h = (state["ssm"].astype(jnp.float32) * jnp.exp(dt * a)[..., None, None]
+         + (dt[..., None] * xs)[..., None] * bh[:, :, None, :])
+    y = jnp.einsum("bhpn,bhn->bhp", h, ch)
+    y = y + params["d_skip"].astype(jnp.float32)[:, None] * xs
+    out = _mamba2_out(params, y.reshape(x.shape[0], 1, -1), z, dims, x.dtype)
+    return out, {"ssm": h, "conv": window[:, 1:]}

@@ -1,9 +1,10 @@
 """Config-driven decoder transformer covering all assigned arch families.
 
-Key structural decisions (see DESIGN.md):
+Key structural decisions:
 
 * Every block group runs as ``lax.scan`` over its layer-stacked params, so
-  HLO size is O(#groups) — 81-layer Zamba2 compiles as ~3 scans.
+  HLO size is O(#groups); Zamba2 scans each run of Mamba2 layers between
+  its hybrid layers (14 scans and 13 hybrid layers at 81 layers).
   Heterogeneous per-layer attention (gemma3 local:global) rides through one
   scan via *traced* per-layer window / rope-theta arrays.
 * Training layer bodies are wrapped in ``jax.checkpoint`` (remat) so the
@@ -45,7 +46,6 @@ from repro.models.attention import (
 from repro.models.config import (
     AttnGroup,
     CrossSelfGroup,
-    MambaGroup,
     ModelConfig,
     MoEGroup,
     XLSTMGroup,
@@ -585,229 +585,305 @@ class _XLSTMGroupImpl(_GroupImpl):
         return x, new_cache
 
 
-class _MambaGroupImpl(_GroupImpl):
-    def __init__(self, spec: MambaGroup, cfg: ModelConfig, n_layers=None):
-        self.spec, self.cfg = spec, cfg
-        self.n_layers = n_layers if n_layers is not None else spec.n_layers
+ATTN_BLOCK = 512  # query rows per block of the Zamba shared block's attention
 
-    def _init_block(self, key, dtype):
-        cfg, spec = self.cfg, self.spec
-        return {"ln": init_rms_norm(cfg.d_model, dtype),
-                "cell": ssm.init_mamba2(key, cfg.d_model, spec.d_state,
-                                        spec.expand, 64, dtype)}
 
-    def init(self, key, dtype):
-        return _stack_init(key, self.n_layers, lambda k: self._init_block(k, dtype))
+def _causal_attn(q, k, v, scale, start=0):
+    """softmax(scale q k^T) v over the keys at or before each query's
+    position; query i sits at position ``start + i``. q/k/v: (B, S, H, D)."""
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                    k.astype(jnp.float32)) * scale
+    qpos = start + jnp.arange(q.shape[1], dtype=jnp.int32)
+    mask = qpos[:, None] >= jnp.arange(k.shape[1], dtype=jnp.int32)[None]
+    probs = jax.nn.softmax(jnp.where(mask, sc, _NEG_INF), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
 
-    def pspec(self):
-        cell = {
-            "w_in": P(None, None, "model"),
-            "w_b": P(None, None, None),
-            "w_c": P(None, None, None),
-            "w_dt": P(None, None, None),
-            "b_dt": P(None, None),
-            "a_log": P(None, None),
-            "d_skip": P(None, None),
-            "w_out": P(None, "model", None),
-        }
-        return {"ln": {"scale": P(None, None)}, "cell": cell}
 
-    def init_cache(self, batch, capacity, dtype):
-        cfg, spec = self.cfg, self.spec
-        st = ssm.mamba2_state(batch, cfg.d_model, spec.d_state, spec.expand, 64)
-        return jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x[None], (self.n_layers,) + x.shape), st)
-
-    def cache_pspec(self, *, batch_axis=None, seq_axis=None):
-        del seq_axis  # O(1) recurrent state has no sequence dim
-        return {"h": P(None, batch_axis, "model", None, None)}
-
-    def train(self, params, x, positions, enc=None, collect_cache=False,
-              use_flash=False):
-        del use_flash  # attention-free
-        cfg = self.cfg
-        b = x.shape[0]
-        cache0 = self.init_cache(b, 0, jnp.float32)
-
-        def body(h, xs):
-            lp, st = xs
-            with phase(PHASE_MODEL_MAMBA2):
-                y, st_new = ssm.mamba2_seq(lp["cell"],
-                                           rms_norm(lp["ln"], h, cfg.norm_eps),
-                                           head_dim=64, state=st)
-                h = h + y
-            return h, (st_new if collect_cache else None)
-
-        x, ys = jax.lax.scan(jax.checkpoint(body), x, (params, cache0))
-        return x, jnp.zeros((), jnp.float32), (ys if collect_cache else None)
-
-    def decode(self, params, x, pos, cache, enc=None):
-        cfg = self.cfg
-
-        def body(h, xs):
-            lp, st = xs
-            y, st_new = ssm.mamba2_step(lp["cell"], rms_norm(lp["ln"], h, cfg.norm_eps),
-                                        st, head_dim=64)
-            return h + y, st_new
-
-        x, new_cache = jax.lax.scan(body, x, (params, cache))
-        return x, new_cache
+def _causal_attn_blocked(q, k, v, scale):
+    """``_causal_attn`` in blocks of ``ATTN_BLOCK`` query rows, each
+    rematerialized, so training holds one block's (B, H, block, S) scores
+    and not the whole (S, S) matrix."""
+    b, s, h, d = q.shape
+    if s <= ATTN_BLOCK or s % ATTN_BLOCK:
+        return _causal_attn(q, k, v, scale)
+    n = s // ATTN_BLOCK
+    blocks = jnp.moveaxis(q.reshape(b, n, ATTN_BLOCK, h, d), 1, 0)
+    starts = jnp.arange(n, dtype=jnp.int32) * ATTN_BLOCK
+    one = jax.checkpoint(lambda qb, st: _causal_attn(qb, k, v, scale, st))
+    out = jax.lax.map(lambda xs: one(*xs), (blocks, starts))
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, h, d)
 
 
 class _ZambaGroupImpl(_GroupImpl):
-    """Units of [mamba_per_unit x Mamba2 + 1 x shared-weight attention].
+    """Zamba2's hybrid stack as ``transformers``' ``modeling_zamba2`` runs
+    it (``Zamba2HybridLayer``, ``Zamba2AttentionDecoderLayer``,
+    ``Zamba2MLP``, ``Zamba2MambaDecoderLayer``; the mixer is
+    ``ssm.mamba2_seq``).
 
-    The attention block's parameters are shared across units (Zamba2's
-    parameter-efficiency trick); each unit application keeps its own KV
-    cache. Trailing Mamba2 layers run after the units.
+    Layer l: h <- h + Mamba2_l(norm_l(h + u_l)), with u_l = 0 in a mamba
+    layer. In the k-th hybrid layer, with tied block j = k mod
+    num_mem_blocks:
+        t   = norm_in_j(concat(h, emb))                       (2 d_model)
+        a   = norm_ff_j(attn_j(t))     RoPE on q/k, scale (head_dim/2)^-0.5
+        u_l = linear_k(down_j(gelu(g) * v)),  [g | v] = gate_up_j(a) + B_k A_k a
+    The block has no residual of its own. ``emb`` is the group's input, the
+    token embedding (Zamba is its model's only group).
+
+    Parameters: ``mamba`` stacks every layer's mixer and pre-norm,
+    ``hybrid`` each application's adapter (A_k, B_k) and ``linear``, and
+    ``shared_blocks`` the tied blocks these layers apply: min(num_mem_blocks,
+    hybrid layers) of them. Prefill and decode keep one KV cache per
+    application and each layer's SSM and conv state.
+
+    A share (rank, size) (``ModelConfig.share``) holds 1/size of each layer:
+    whole B/C groups with their heads, conv channels, norm group and
+    out_proj rows; attention heads with their o_proj rows; MLP columns of
+    gate and up with the matching adapter-B columns and down rows. Norms,
+    ``linear`` and adapter A are whole. Each layer's output is the share's
+    partial sum, and that is what the next layer reads.
+
+    Departures from the published model: none in the equations. Attention
+    runs in blocks of ``ATTN_BLOCK`` query rows, and the mixer's SSD in
+    chunks of min(chunk_size, L) (``ssm.ssd_chunked``). With
+    ``flash_prefill`` the prefill's attention runs through the flash
+    kernel, q rescaled to Zamba's scale.
     """
 
     def __init__(self, spec: ZambaGroup, cfg: ModelConfig):
         self.spec, self.cfg = spec, cfg
-        mg = MambaGroup(n_layers=spec.mamba_per_unit, d_state=spec.d_state,
-                        expand=spec.expand)
-        self._mamba_unit = _MambaGroupImpl(mg, cfg, n_layers=spec.mamba_per_unit)
-        self._trailing = (_MambaGroupImpl(
-            MambaGroup(n_layers=spec.trailing_mamba, d_state=spec.d_state,
-                       expand=spec.expand), cfg, n_layers=spec.trailing_mamba)
-            if spec.trailing_mamba else None)
+        size = cfg.share[1]
+        nh = spec.expand * cfg.d_model // spec.mamba_headdim
+        for what, n in (("Mamba2 heads", nh), ("B/C groups", spec.mamba_ngroups),
+                        ("attention heads", cfg.n_heads),
+                        ("MLP columns", cfg.d_ff)):
+            if n % size:
+                raise ValueError(f"{n} {what} do not divide into {size} shares")
+        if cfg.n_kv_heads != cfg.n_heads:
+            raise ValueError("Zamba2 attention has as many kv heads as heads")
+        self.dims = ssm.Mamba2Dims(
+            n_heads=nh // size, head_dim=spec.mamba_headdim,
+            n_groups=spec.mamba_ngroups // size, d_state=spec.d_state,
+            d_conv=spec.d_conv, chunk_size=spec.chunk_size,
+            dt_min=spec.time_step_min)
+        self.heads = cfg.n_heads // size
+        self.ff = cfg.d_ff // size
+        self.n_layers = spec.total_layers
+        self.hybrid = spec.hybrid_layer_ids
+        self.n_blocks = min(spec.num_mem_blocks, len(self.hybrid))
+        self.scale = (cfg.head_dim / 2) ** -0.5
 
+    # -- parameters and caches ------------------------------------------------
     def init(self, key, dtype):
-        k1, k2, k3 = jax.random.split(key, 3)
-        params = {
-            "units_mamba": _stack_init(
-                k1, self.spec.n_units, lambda k: self._mamba_unit.init(k, dtype)),
-            "shared_attn": _init_attn_block(k2, self.cfg, dtype),
-        }
-        if self._trailing is not None:
-            params["trailing"] = self._trailing.init(k3, dtype)
-        return params
+        cfg, d = self.cfg, self.cfg.d_model
+        w = self.heads * cfg.head_dim
+        km, kh, kb = jax.random.split(key, 3)
+
+        def mamba(k):
+            return {"ln": init_rms_norm(d, dtype),
+                    "mixer": ssm.init_mamba2(k, d, self.dims, dtype)}
+
+        def hybrid(k):
+            k1, k2, k3 = jax.random.split(k, 3)
+            r = self.spec.adapter_rank
+            return {"adapter_a": dense_init(k1, (d, r), dtype),
+                    "adapter_b": dense_init(k2, (r, 2 * self.ff), dtype),
+                    "linear": dense_init(k3, (d, d), dtype)}
+
+        def block(k):
+            ks = jax.random.split(k, 6)
+            return {"ln_in": init_rms_norm(2 * d, dtype),
+                    "wq": dense_init(ks[0], (2 * d, w), dtype),
+                    "wk": dense_init(ks[1], (2 * d, w), dtype),
+                    "wv": dense_init(ks[2], (2 * d, w), dtype),
+                    "wo": dense_init(ks[3], (w, d), dtype),
+                    "ln_ff": init_rms_norm(d, dtype),
+                    "gate_up": dense_init(ks[4], (d, 2 * self.ff), dtype),
+                    "down": dense_init(ks[5], (self.ff, d), dtype)}
+
+        return {"mamba": _stack_init(km, self.n_layers, mamba),
+                "hybrid": _stack_init(kh, len(self.hybrid), hybrid),
+                "shared_blocks": _stack_init(kb, self.n_blocks, block)}
 
     def pspec(self):
-        unit_m = jax.tree_util.tree_map(
-            lambda spec: P(*((None,) + tuple(spec))), self._mamba_unit.pspec(),
-            is_leaf=lambda x: isinstance(x, P))
-        out = {
-            "units_mamba": unit_m,
-            "shared_attn": _attn_block_pspec(self.cfg, prefix=()),
-        }
-        if self._trailing is not None:
-            out["trailing"] = self._trailing.pspec()
-        return out
+        """Tensor parallelism over the mesh's ``model`` axis: the mixer's
+        in_proj columns, conv channels, heads and out_proj rows; attention
+        heads with their o_proj rows; MLP columns with the matching
+        adapter-B columns and down rows. Norms of d_model, adapter A and
+        ``linear`` are whole."""
+        rep2, rep3 = P(None, None), P(None, None, None)
+        cols2, cols3 = P(None, "model"), P(None, None, "model")
+        rows3 = P(None, "model", None)
+        mixer = {"in_proj": cols3, "conv_w": cols3, "out_proj": rows3}
+        mixer.update({k: cols2 for k in ("conv_b", "dt_bias", "a_log",
+                                         "d_skip", "norm")})
+        block = {"ln_in": {"scale": rep2}, "ln_ff": {"scale": rep2},
+                 "wq": cols3, "wk": cols3, "wv": cols3, "wo": rows3,
+                 "gate_up": cols3, "down": rows3}
+        return {"mamba": {"ln": {"scale": rep2}, "mixer": mixer},
+                "hybrid": {"adapter_a": rep3, "adapter_b": cols3,
+                           "linear": rep3},
+                "shared_blocks": block}
 
     def init_cache(self, batch, capacity, dtype):
-        cfg, spec = self.cfg, self.spec
-        m = self._mamba_unit.init_cache(batch, capacity, dtype)
-        m = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x[None], (spec.n_units,) + x.shape), m)
-        kv_shape = (spec.n_units, batch, capacity, cfg.n_kv_heads, cfg.head_dim)
-        cache = {"mamba": m,
-                 "attn": {"k": jnp.zeros(kv_shape, dtype),
-                          "v": jnp.zeros(kv_shape, dtype)}}
-        if self._trailing is not None:
-            cache["trailing"] = self._trailing.init_cache(batch, capacity, dtype)
-        return cache
+        st = ssm.mamba2_state(batch, self.dims, dtype)
+        kv = (len(self.hybrid), batch, capacity, self.heads, self.cfg.head_dim)
+        return {"mamba": jax.tree_util.tree_map(
+                    lambda a: jnp.broadcast_to(a[None], (self.n_layers,) + a.shape),
+                    st),
+                "attn": {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}}
 
     def cache_pspec(self, *, batch_axis=None, seq_axis=None):
-        m = jax.tree_util.tree_map(
-            lambda spec: P(*((None,) + tuple(spec))),
-            self._mamba_unit.cache_pspec(batch_axis=batch_axis),
-            is_leaf=lambda x: isinstance(x, P))
         kv = P(None, batch_axis, seq_axis,
-               "model" if self.cfg.n_kv_heads % 16 == 0 else None, None)
-        out = {"mamba": m, "attn": {"k": kv, "v": kv}}
-        if self._trailing is not None:
-            out["trailing"] = self._trailing.cache_pspec(batch_axis=batch_axis)
+               "model" if self.heads % 16 == 0 else None, None)
+        return {"mamba": {"ssm": P(None, batch_axis, "model", None, None),
+                          "conv": P(None, batch_axis, None, "model")},
+                "attn": {"k": kv, "v": kv}}
+
+    # -- the layers --------------------------------------------------------------
+    def _mamba(self, lp, h, u, st, step=False):
+        with phase(PHASE_MODEL_MAMBA2):
+            x = rms_norm(lp["ln"], h + u, self.cfg.norm_eps)
+            fn = ssm.mamba2_step if step else ssm.mamba2_seq
+            y, st = fn(lp["mixer"], x, self.dims, st)
+            return h + y, st
+
+    def _qkv(self, bp, t, positions):
+        b, s, _ = t.shape
+        shape = (b, s, self.heads, self.cfg.head_dim)
+        theta = self.cfg.rope_theta
+        q = rope((t @ bp["wq"]).reshape(shape), positions, theta)
+        k = rope((t @ bp["wk"]).reshape(shape), positions, theta)
+        return q, k, (t @ bp["wv"]).reshape(shape)
+
+    def _attention(self, bp, t, attend):
+        """o_proj(attention(t)); ``attend(bp, t) -> (out, kv)`` runs the
+        attention proper (training or decode)."""
+        out, kv = attend(bp, t)
+        return out.reshape(t.shape[:2] + (-1,)).astype(t.dtype) @ bp["wo"], kv
+
+    def _mlp(self, bp, hp, a):
+        """linear_k(down(gelu(g) * up)), [g | up] = a W_gu + (a A_k) B_k."""
+        gu = a @ bp["gate_up"] + (a @ hp["adapter_a"]) @ hp["adapter_b"]
+        g, up = jnp.split(gu, 2, axis=-1)
+        g = jax.nn.gelu(g.astype(jnp.float32), approximate=False)
+        return ((g.astype(a.dtype) * up) @ bp["down"]) @ hp["linear"]
+
+    def _block(self, bp, hp, h, emb, attend):
+        """The tied block's contribution u to a hybrid layer."""
+        eps = self.cfg.norm_eps
+        with phase(PHASE_MODEL_ATTN):
+            t = rms_norm(bp["ln_in"], jnp.concatenate([h, emb], axis=-1), eps)
+            a, kv = self._attention(bp, t, attend)
+        with phase(PHASE_MODEL_MLP):
+            return self._mlp(bp, hp, rms_norm(bp["ln_ff"], a, eps)), kv
+
+    def _train_attend(self, positions, use_flash=False):
+        def attend(bp, t):
+            q, k, v = self._qkv(bp, t, positions)
+            if use_flash:
+                from repro.kernels import ops as kops
+
+                # The kernel scales by head_dim^-0.5; Zamba by (head_dim/2)^-0.5.
+                rescale = self.scale * self.cfg.head_dim ** 0.5
+                return kops.flash_attention_bshd(q * rescale, k, v), (k, v)
+            return _causal_attn_blocked(q, k, v, self.scale), (k, v)
+
+        return attend
+
+    def _layer_params(self, params, k, layer):
+        pick = lambda tree, i: jax.tree_util.tree_map(lambda a: a[i], tree)  # noqa: E731
+        return (pick(params["shared_blocks"], k % self.n_blocks),
+                pick(params["hybrid"], k), pick(params["mamba"], layer))
+
+    def _runs(self):
+        """(first, end, hybrid application or None) of each piece in layer
+        order: runs of mamba layers scan; a hybrid layer is one piece."""
+        out, start = [], 0
+        for k, layer in enumerate(self.hybrid):
+            if layer > start:
+                out.append((start, layer, None))
+            out.append((layer, layer + 1, k))
+            start = layer + 1
+        if start < self.n_layers:
+            out.append((start, self.n_layers, None))
         return out
 
     def train(self, params, x, positions, enc=None, collect_cache=False,
               use_flash=False):
-        cfg = self.cfg
-        shared = params["shared_attn"]
+        emb, h = x, x
+        states, kvs = [], []
+        attend = self._train_attend(positions, use_flash)
 
-        def body(h, up):
-            h, _, m_cache = self._mamba_unit.train(up, h, positions,
-                                                   collect_cache=collect_cache)
-            with phase(PHASE_MODEL_ATTN):
-                a, k, v = _attn_train(shared["attn"],
-                                      rms_norm(shared["ln1"], h, cfg.norm_eps),
-                                      positions, cfg,
-                                      jnp.asarray(cfg.rope_theta, jnp.float32),
-                                      jnp.asarray(-1, jnp.int32),
-                                      use_flash=use_flash)
-                h = h + a
-            with phase(PHASE_MODEL_MLP):
-                h = h + mlp_apply(shared["mlp"],
-                                  rms_norm(shared["ln2"], h, cfg.norm_eps),
-                                  cfg.activation)
-            ys = (m_cache, k, v) if collect_cache else None
-            return h, ys
+        def hybrid_layer(bp, hp, lp, h):
+            u, kv = self._block(bp, hp, h, emb, attend)
+            h, st = self._mamba(lp, h, u, None)
+            return h, ((st, kv) if collect_cache else None)
 
-        x, ys = jax.lax.scan(jax.checkpoint(body), x, params["units_mamba"])
+        def mamba_layer(h, lp):
+            h, st = self._mamba(lp, h, 0.0, None)
+            return h, (st if collect_cache else None)
+
+        for first, end, k in self._runs():
+            if k is None:
+                run = jax.tree_util.tree_map(lambda a: a[first:end],
+                                             params["mamba"])
+                h, st = jax.lax.scan(jax.checkpoint(mamba_layer), h, run)
+            else:
+                h, out = jax.checkpoint(hybrid_layer)(
+                    *self._layer_params(params, k, first), h)
+                st = None
+                if collect_cache:
+                    st = jax.tree_util.tree_map(lambda a: a[None], out[0])
+                    kvs.append(out[1])
+            states.append(st)
         cache = None
         if collect_cache:
-            cache = {"mamba": ys[0], "attn": {"k": ys[1], "v": ys[2]}}
-        aux = jnp.zeros((), jnp.float32)
-        if self._trailing is not None:
-            x, _, tr_cache = self._trailing.train(params["trailing"], x, positions,
-                                                  collect_cache=collect_cache)
-            if collect_cache:
-                cache["trailing"] = tr_cache
-        return x, aux, cache
+            cache = {"mamba": jax.tree_util.tree_map(
+                         lambda *a: jnp.concatenate(a), *states),
+                     "attn": {"k": jnp.stack([kv[0] for kv in kvs]),
+                              "v": jnp.stack([kv[1] for kv in kvs])}}
+        return h, jnp.zeros((), jnp.float32), cache
 
     def decode(self, params, x, pos, cache, enc=None):
-        cfg = self.cfg
-        shared = params["shared_attn"]
+        """One token. Each application writes its token's k/v into its slot
+        of the stacked KV cache in place, which is what
+        ``decode_cache_in_carry`` asks of the other groups."""
+        emb, h = x, x
+        b = x.shape[0]
+        states = []
+        mc = cache["mamba"]
+        k_all, v_all = cache["attn"]["k"], cache["attn"]["v"]
 
-        if cfg.decode_cache_in_carry:
-            idxs = jnp.arange(self.spec.n_units, dtype=jnp.int32)
+        for first, end, k in self._runs():
+            run_st = jax.tree_util.tree_map(lambda a: a[first:end], mc)
+            if k is None:
+                run = jax.tree_util.tree_map(lambda a: a[first:end],
+                                             params["mamba"])
 
-            def body_c(carry, xs):
-                h, k_all, v_all = carry
-                up, m_st, i = xs
-                h, m_new = self._mamba_unit.decode(up, h, pos, m_st)
-                a, k_all, v_all = _attn_decode_carry(
-                    shared["attn"], rms_norm(shared["ln1"], h, cfg.norm_eps),
-                    pos, k_all, v_all, i, cfg,
-                    jnp.asarray(cfg.rope_theta, jnp.float32),
-                    jnp.asarray(-1, jnp.int32))
-                h = h + a
-                h = h + mlp_apply(shared["mlp"],
-                                  rms_norm(shared["ln2"], h, cfg.norm_eps),
-                                  cfg.activation)
-                return (h, k_all, v_all), m_new
+                def body(h, xs):
+                    lp, st = xs
+                    return self._mamba(lp, h, 0.0, st, step=True)
 
-            (x, k, v), m_new = jax.lax.scan(
-                body_c, (x, cache["attn"]["k"], cache["attn"]["v"]),
-                (params["units_mamba"], cache["mamba"], idxs))
-            new_cache = {"mamba": m_new, "attn": {"k": k, "v": v}}
-            if self._trailing is not None:
-                x, tr = self._trailing.decode(params["trailing"], x, pos,
-                                              cache["trailing"])
-                new_cache["trailing"] = tr
-            return x, new_cache
+                h, st = jax.lax.scan(body, h, (run, run_st))
+            else:
+                def attend(bp, t, k=k, k_all=k_all, v_all=v_all):
+                    q, kn, vn = self._qkv(bp, t, jnp.full((b, 1), pos, jnp.int32))
+                    at = (k, 0, pos, 0, 0)
+                    k_all = jax.lax.dynamic_update_slice(
+                        k_all, kn[None].astype(k_all.dtype), at)
+                    v_all = jax.lax.dynamic_update_slice(
+                        v_all, vn[None].astype(v_all.dtype), at)
+                    return (_causal_attn(q, k_all[k], v_all[k], self.scale, pos),
+                            (k_all, v_all))
 
-        def body(h, xs):
-            up, m_st, kc, vc = xs
-            h, m_new = self._mamba_unit.decode(up, h, pos, m_st)
-            a, kc, vc = _attn_decode(shared["attn"],
-                                     rms_norm(shared["ln1"], h, cfg.norm_eps),
-                                     pos, kc, vc, cfg,
-                                     jnp.asarray(cfg.rope_theta, jnp.float32),
-                                     jnp.asarray(-1, jnp.int32), False)
-            h = h + a
-            h = h + mlp_apply(shared["mlp"], rms_norm(shared["ln2"], h, cfg.norm_eps),
-                              cfg.activation)
-            return h, (m_new, kc, vc)
-
-        x, (m_new, k, v) = jax.lax.scan(
-            body, x, (params["units_mamba"], cache["mamba"],
-                      cache["attn"]["k"], cache["attn"]["v"]))
-        new_cache = {"mamba": m_new, "attn": {"k": k, "v": v}}
-        if self._trailing is not None:
-            x, tr = self._trailing.decode(params["trailing"], x, pos, cache["trailing"])
-            new_cache["trailing"] = tr
-        return x, new_cache
+                bp, hp, lp = self._layer_params(params, k, first)
+                u, (k_all, v_all) = self._block(bp, hp, h, emb, attend)
+                h, st = self._mamba(lp, h, u, jax.tree_util.tree_map(
+                    lambda a: a[0], run_st), step=True)
+                st = jax.tree_util.tree_map(lambda a: a[None], st)
+            states.append(st)
+        mamba = jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *states)
+        return h, {"mamba": mamba, "attn": {"k": k_all, "v": v_all}}
 
 
 class _CrossSelfGroupImpl(_GroupImpl):
@@ -902,7 +978,6 @@ _GROUP_IMPLS = {
     "attn": _AttnGroupImpl,
     "moe": _MoEGroupImpl,
     "xlstm": _XLSTMGroupImpl,
-    "mamba": _MambaGroupImpl,
     "zamba": _ZambaGroupImpl,
     "cross_self": _CrossSelfGroupImpl,
 }
@@ -925,16 +1000,29 @@ class Transformer:
     def dtype(self):
         return jnp.dtype(self.cfg.param_dtype)
 
+    @property
+    def vocab(self) -> tuple[int, int]:
+        """(first id, rows) of the vocabulary slice this share holds."""
+        rank, size = self.cfg.share
+        rows = self.cfg.vocab_size // size
+        return rank * rows, rows
+
+    def _rows(self, ids):
+        """Token ids -> rows of the held vocabulary slice."""
+        first = self.vocab[0]
+        return ids - first if first else ids
+
     # -- parameters -----------------------------------------------------------
     def init(self, key: jax.Array) -> dict:
         cfg = self.cfg
         keys = jax.random.split(key, len(self.groups) + 2)
+        rows = self.vocab[1]
         params: dict[str, Any] = {
-            "embed": dense_init(keys[0], (cfg.vocab_size, cfg.d_model), self.dtype),
+            "embed": dense_init(keys[0], (rows, cfg.d_model), self.dtype),
             "final_ln": init_rms_norm(cfg.d_model, self.dtype),
         }
         if not cfg.tie_embedding:
-            params["lm_head"] = dense_init(keys[1], (cfg.d_model, cfg.vocab_size),
+            params["lm_head"] = dense_init(keys[1], (cfg.d_model, rows),
                                            self.dtype)
         for i, g in enumerate(self.groups):
             params[f"group_{i}"] = g.init(keys[i + 2], self.dtype)
@@ -959,7 +1047,7 @@ class Transformer:
             if cfg.input_mode == "embeddings":
                 x = batch["embeds"].astype(self.dtype)
             else:
-                x = params["embed"][batch["tokens"]]
+                x = params["embed"][self._rows(batch["tokens"])]
             if cfg.embed_scale:
                 x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
             return x
@@ -1007,7 +1095,7 @@ class Transformer:
             labels = self._labels(batch)
             # predict token t+1 from hidden t
             h = h[:, :-1]
-            targets = labels[:, 1:]
+            targets = self._rows(labels[:, 1:])
             b, sm1, d = h.shape
             chunk = min(self.LOSS_CHUNK, sm1)
             n_chunks = sm1 // chunk
@@ -1070,7 +1158,7 @@ class Transformer:
         if cfg.input_mode == "embeddings":
             x = token[:, None, :].astype(self.dtype)
         else:
-            x = params["embed"][token][:, None, :]
+            x = params["embed"][self._rows(token)][:, None, :]
         if cfg.embed_scale:
             x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
         new_cache = {}

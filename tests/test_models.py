@@ -161,10 +161,13 @@ def test_ssm_step_matches_seq(cell):
         state = ssm.slstm_state(B, d)
         step = lambda xt, st: ssm.slstm_step(p, xt, st)
     else:
-        p = ssm.init_mamba2(key, d, d_state=8, head_dim=8)
-        y_seq, _ = ssm.mamba2_seq(p, x, head_dim=8)
-        state = ssm.mamba2_state(B, d, 8, 2, 8)
-        step = lambda xt, st: ssm.mamba2_step(p, xt, st, head_dim=8)
+        # 4 heads of 8 in 2 B/C groups; SSD chunks of 4 (2 chunks, 1 padded)
+        dims = ssm.Mamba2Dims(n_heads=4, head_dim=8, n_groups=2, d_state=8,
+                              chunk_size=4)
+        p = ssm.init_mamba2(key, d, dims)
+        y_seq, _ = ssm.mamba2_seq(p, x, dims)
+        state = ssm.mamba2_state(B, dims)
+        step = lambda xt, st: ssm.mamba2_step(p, xt, dims, st)
     ys = []
     for t in range(S):
         y, state = step(x[:, t:t + 1], state)
